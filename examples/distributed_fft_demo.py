@@ -1,7 +1,10 @@
 """Distributed pencil-FFT demo on 8 simulated devices: the pod-scale FFT
 path of DESIGN.md §2, validated against numpy.
 
-Re-execs itself with XLA_FLAGS so the host presents 8 devices.
+Re-execs itself with XLA_FLAGS so the host presents 8 devices.  CPU only:
+the re-exec pins JAX to the CPU backend, so on a TPU host the demo never
+claims the chips (the four-chip path on hardware is
+``python chip_smoke.py --chips 4``).
 
   PYTHONPATH=src python examples/distributed_fft_demo.py
 """
@@ -11,6 +14,7 @@ import sys
 
 if os.environ.get("XLA_FLAGS", "").find("host_platform_device_count") < 0:
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.execv(sys.executable, [sys.executable] + sys.argv)
 
 import numpy as np                                    # noqa: E402

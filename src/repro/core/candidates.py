@@ -101,6 +101,30 @@ BACKENDS = ("xla", "stockham", "fourstep", "dft", "fourstep_pallas",
             "stockham_pallas", "sixstep", "fft2_pallas", "chirpz_pallas",
             "bluestein")
 
+#: Withdrawn on the TPU.  The mixed-radix Stockham stage chain
+#: (``stockham_pallas.apply_stages``) splits the lane axis with
+#: ``x.reshape(*lead, r, m, s)`` and slices twiddles at unaligned static
+#: offsets, which Mosaic refuses ("infer-vector-layout: unsupported shape
+#: cast"); sixstep, fft2_pallas and chirpz_pallas run that chain too.
+#: Until a Mosaic-friendly Stockham exists the planner never offers them
+#: there, so no fallback chain has to hide a failed compile.
+TPU_WITHDRAWN = frozenset({"stockham_pallas", "sixstep", "fft2_pallas",
+                           "chirpz_pallas"})
+
+
+def platform_allows(backend: str, precision: str = "float") -> bool:
+    """Can ``backend`` run on this platform at ``precision``?  Everything
+    runs off the TPU (Pallas kernels interpreted).  On the TPU the
+    :data:`TPU_WITHDRAWN` kernels never run, and nothing runs in double:
+    XLA's TPU FFT rejects c128 operands, the TPU compiler aborts on f64
+    matmuls, and Mosaic lowers no f64 planes."""
+    from .device import on_tpu
+
+    if not on_tpu():
+        return True
+    return precision != "double" and backend not in TPU_WITHDRAWN
+
+
 #: Mesh-sharded decompositions (fft/distributed.py) — enumerated only when
 #: an active mesh is installed (launch.mesh.set_active_mesh), and kept out
 #: of :data:`BACKENDS` so single-device planning and the conformance
@@ -113,13 +137,17 @@ DIST_A2A_COUNT = {"dist1d": 2, "slab": 1, "pencil": 2}
 DIST_NATURAL_EXTRA = {"dist1d": 1, "slab": 1, "pencil": 2}
 
 
-def axis_feasible(backend: str, n: int) -> bool:
+def axis_feasible(backend: str, n: int, precision: str = "float") -> bool:
     """Can ``backend`` transform one batched axis of extent ``n``?  This is
     the engine-level contract: the length the cfft actually receives — n//2
     for the packed r2c innermost axis of an EVEN real extent, the full
     length for an odd one, see ``axis_engine_n``.  The chirp backends are
     the any-length catch-all, so odd-length real kinds explicitly route to
-    the full-complex chirp path rather than a meaningless packed half."""
+    the full-complex chirp path rather than a meaningless packed half.
+    Backends the platform withdraws (:func:`platform_allows`) are never
+    feasible."""
+    if not platform_allows(backend, precision):
+        return False
     if backend in ("xla", "bluestein"):
         return True
     if backend == "stockham":
@@ -166,6 +194,8 @@ def fft2_feasible(problem: Problem) -> bool:
 def backend_supports(backend: str, problem: Problem) -> bool:
     """Single source of truth for the support matrix: candidates(), the
     conformance matrix, and the README table all consult this."""
+    if not platform_allows(backend, problem.precision):
+        return False
     if backend == "fft2_pallas":
         return fft2_feasible(problem)
     if backend == "xla":
@@ -175,7 +205,8 @@ def backend_supports(backend: str, problem: Problem) -> bool:
         if not all(_pow2(v) and SIXSTEP_MIN_N <= v <= SIXSTEP_MAX_N
                    for v in problem.extents):
             return False
-    return all(axis_feasible(backend, axis_engine_n(problem, i))
+    return all(axis_feasible(backend, axis_engine_n(problem, i),
+                             problem.precision)
                for i in range(problem.rank))
 
 
@@ -196,7 +227,8 @@ def dist_supports(backend: str, problem: Problem,
     rotation depends on.  ``dist1d`` additionally needs batch == 1 — its
     matrix view consumes the whole axis.
     """
-    if not problem.complex_input:
+    if not problem.complex_input \
+            or not platform_allows(backend, problem.precision):
         return False
     from repro.fft import distributed as dist
 
@@ -275,10 +307,12 @@ def _dist_candidates(problem: Problem, mesh, patient: bool
         extra = []
         for c in out:
             lengths = [n for n, _ in dist_local_lengths(problem, c)]
-            default = {dist_local_engine(n) for n in lengths}
+            default = {dist_local_engine(n, problem.precision)
+                       for n in lengths}
             locals_ = [b for b in BACKENDS
                        if b not in FUSED_ND and b not in default
-                       and all(axis_feasible(b, n) for n in lengths)
+                       and all(axis_feasible(b, n, problem.precision)
+                               for n in lengths)
                        and all(hbm_passes(b, n) != float("inf")
                                for n in lengths)]
             locals_.sort(key=lambda b: sum(hbm_passes(b, n) for n in lengths))
@@ -308,14 +342,16 @@ def candidates(problem: Problem, patient: bool = False,
     offers a multi-device plan.
     """
     exts = problem.extents
-    out: list[Candidate] = [Candidate("xla")]
     # every backend — the chirp catch-alls included — goes through
     # backend_supports, which evaluates feasibility at the ENGINE length:
     # odd-length real kinds route to the full-complex chirp path (engine
     # length n, not the even-only packed n//2) and caps apply there
-    for b in BACKENDS[1:]:
-        if backend_supports(b, problem):
-            out.append(Candidate(b))
+    out = [Candidate(b) for b in BACKENDS if backend_supports(b, problem)]
+    if not out:
+        from .device import platform
+
+        raise ValueError(f"no backend runs {problem.signature()} on the "
+                         f"{platform()} platform")
     if problem.rank >= 2:
         out += _mixed_candidates(problem, limit=12 if patient else 6)
     if mesh is None:
@@ -387,7 +423,8 @@ def _mixed_candidates(problem: Problem, limit: int) -> list[Candidate]:
     for i in range(problem.rank):
         n_eng = axis_engine_n(problem, i)
         feas = [b for b in BACKENDS
-                if b not in FUSED_ND and axis_feasible(b, n_eng)]
+                if b not in FUSED_ND
+                and axis_feasible(b, n_eng, problem.precision)]
         feas.sort(key=lambda b: hbm_passes(b, n_eng))
         per_axis.append(feas[:2])
     scored = []

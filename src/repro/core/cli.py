@@ -34,6 +34,7 @@ import importlib
 from typing import Sequence
 
 from .client import KINDS, PRECISIONS
+from .device import setup_compile_cache
 from .plan import PlanRigor
 from .registry import client_names
 from .suite import Session, SuiteSpec
@@ -119,6 +120,9 @@ def _explicit_args(argv: Sequence[str] | None) -> set[str]:
 
 
 def main(argv: Sequence[str] | None = None) -> int:
+    """Run the suite; exit status 1 when nothing is selected or any node
+    failed (the suite itself records a failure and carries on)."""
+    setup_compile_cache()
     # --load/--config run before the main parse so the clients they register
     # appear in --client choices
     pre = argparse.ArgumentParser(add_help=False)
@@ -160,7 +164,7 @@ def main(argv: Sequence[str] | None = None) -> int:
         s = result.plan_stats
         print(f"plan cache: {s.hits} hits, {s.misses} misses, "
               f"cold compile {s.cold_ms:.0f} ms")
-    return 0
+    return 1 if result.n_failures else 0
 
 
 if __name__ == "__main__":

@@ -42,7 +42,7 @@ def dist_engines(problem: Problem, cand: Candidate) -> list:
     forced = cand.opts().get("local")
     out = []
     for n, _ in dist_local_lengths(problem, cand):
-        b = forced or dist_local_engine(n)
+        b = forced or dist_local_engine(n, problem.precision)
         out.append(_engine(Candidate(b)))
     return out
 

@@ -5,7 +5,7 @@ Backend map (DESIGN.md §2):
   xla              XLA's native FFT HLO ("vendor library", whole-ND)
   stockham         pure-jnp Stockham autosort (radix-2 butterfly baseline)
   fourstep         matmul-DFT four-step (MXU formulation, jnp)
-  fourstep_pallas  fused four-step Pallas kernel, n <= 16384 (interpret off-TPU)
+  fourstep_pallas  fused four-step Pallas kernel, n <= 16384
   stockham_pallas  fused multi-stage Stockham Pallas kernel: every radix
                    stage on a VMEM-resident batch tile, one HBM touch
                    (knobs: tile_b, radix)
@@ -51,6 +51,7 @@ import jax
 import jax.numpy as jnp
 
 from ..client import Context, FFTClient, Problem
+from ..device import interpret_mode
 from ..plan import (Candidate, Plan, PlanCache, PlanRigor, cached_build,
                     executable_bytes, make_plan)
 from ..registry import register_client
@@ -59,13 +60,14 @@ from repro.fft import bluestein, fourstep, nd, stockham
 from repro.fft import rfft as rfft_mod
 
 
-def _on_tpu() -> bool:
-    return jax.devices()[0].platform == "tpu"
-
-
 def _engine(cand: Candidate) -> Callable:
-    """Return cfft(x, inverse=False) transforming the LAST axis."""
+    """Return cfft(x, inverse=False) transforming the LAST axis.  Pallas
+    engines get ``interpret`` from :func:`repro.core.device.interpret_mode`
+    (the one platform decision), passed as a concrete value so jit caches
+    never share a trace between interpreted and compiled kernels."""
     b = cand.backend
+    opts = cand.opts()
+    interp = interpret_mode()
     if b == "stockham":
         return stockham.fft
     if b == "fourstep":
@@ -73,42 +75,36 @@ def _engine(cand: Candidate) -> Callable:
     if b == "bluestein":
         return bluestein.fft   # staged jnp chirp-Z baseline
     if b == "chirpz_pallas":
-        opts = cand.opts()
         engine = opts.get("engine", "auto")
         tile_b = opts.get("tile_b")
-        interp = not _on_tpu()
         return lambda x, inverse=False: bluestein.fft(x, inverse=inverse,
                                                       engine=engine,
                                                       tile_b=tile_b,
                                                       interpret=interp)
     if b == "fourstep_pallas":
         from repro.kernels.fft4step import ops as fs_ops
-        tile_b = cand.opts().get("tile_b", 8)
-        interp = not _on_tpu()
+        tile_b = opts.get("tile_b", 8)
         return lambda x, inverse=False: fs_ops.fft(x, inverse=inverse,
-                                                   tile_b=tile_b, interpret=interp)
+                                                   tile_b=tile_b,
+                                                   interpret=interp)
     if b == "stockham_pallas":
         from repro.kernels.stockham_pallas import ops as sp_ops
-        opts = cand.opts()
         tile_b = opts.get("tile_b")
         radix = opts.get("radix", 8)
-        interp = not _on_tpu()
         return lambda x, inverse=False: sp_ops.fft(x, inverse=inverse,
                                                    tile_b=tile_b, radix=radix,
                                                    interpret=interp)
     if b == "sixstep":
         from repro.fft import sixstep
-        opts = cand.opts()
         split_n1 = opts.get("split_n1")
         tile_b = opts.get("tile_b")
-        interp = not _on_tpu()
         return lambda x, inverse=False: sixstep.fft(x, inverse=inverse,
                                                     n1=split_n1, tile_b=tile_b,
                                                     interpret=interp)
     if b == "dft":
         from repro.kernels.dft_matmul import ops as dft_ops
-        interp = not _on_tpu()
-        return lambda x, inverse=False: dft_ops.dft(x, inverse=inverse, interpret=interp)
+        return lambda x, inverse=False: dft_ops.dft(x, inverse=inverse,
+                                                    interpret=interp)
     raise ValueError(f"unknown backend {b!r}")
 
 
@@ -119,7 +115,7 @@ def _fft2_engine(cand: Candidate) -> Callable:
     opts = cand.opts()
     tile_b = opts.get("tile_b")
     radix = opts.get("radix", 8)
-    interp = not _on_tpu()
+    interp = interpret_mode()
     return lambda x, inverse=False: f2_ops.fft2(x, inverse=inverse,
                                                 tile_b=tile_b, radix=radix,
                                                 interpret=interp)

@@ -307,9 +307,10 @@ class CostModel:
             forced = opts.get("local")
             passes = 0.0
             for n_g, swaps in dist_local_lengths(problem, cand):
-                b = forced or self.dist_local_engine(n_g)
+                b = forced or self.dist_local_engine(n_g, problem.precision)
                 hp = self.hbm_passes(b, n_g)
-                if hp == float("inf") or not axis_feasible(b, n_g):
+                if hp == float("inf") or not axis_feasible(
+                        b, n_g, problem.precision):
                     return Infeasible(
                         f"local engine {b} infeasible at n={n_g}")
                 passes += hp + swaps
@@ -362,7 +363,7 @@ class CostModel:
         return float(self.estimate(problem, cand))
 
     # --- rankings ---------------------------------------------------------
-    def dist_local_engine(self, n: int) -> str:
+    def dist_local_engine(self, n: int, precision: str = "float") -> str:
         """The separable backend a distributed plan runs locally at length
         ``n`` when no explicit ``local`` knob forces one: fewest modeled
         HBM passes, ties to the earlier (more conservative) BACKENDS
@@ -371,7 +372,7 @@ class CostModel:
         for b in BACKENDS:
             if b in FUSED_ND:
                 continue
-            if axis_feasible(b, n):
+            if axis_feasible(b, n, precision):
                 passes = self.hbm_passes(b, n)
                 if passes < best_p:
                     best, best_p = b, passes
@@ -449,8 +450,8 @@ def estimate_choice(problem: Problem) -> Candidate:
     return get_active_model().estimate_choice(problem)
 
 
-def dist_local_engine(n: int) -> str:
-    return get_active_model().dist_local_engine(n)
+def dist_local_engine(n: int, precision: str = "float") -> str:
+    return get_active_model().dist_local_engine(n, precision)
 
 
 def _axis_elems(problem: Problem, axis: int) -> int:

@@ -18,8 +18,8 @@ Engine selection (the planner's ``chirpz_pallas`` backend vs the staged
 ``bluestein`` baseline): the two per-call padded pow2 transforms run through
 a selectable engine — the fused in-VMEM ``stockham_pallas`` kernel, the
 ``sixstep`` composition for padded lengths past the VMEM tile budget, or the
-staged pure-jnp ``stockham`` fallback.  ``engine="auto"`` picks by padded
-length.
+staged pure-jnp ``stockham`` engine.  ``engine="auto"`` picks by padded
+length on hardware and the jnp engine in interpret mode.
 
 Host-side setup is cached, not recomputed per call: the chirp c and the
 padded filter spectrum FFT(b) depend only on (n, dtype, direction), so they
@@ -40,6 +40,7 @@ from __future__ import annotations
 import numpy as np
 import jax.numpy as jnp
 
+from repro.core.device import interpret_mode
 from repro.core.extents import next_pow2 as _next_pow2, next_smooth
 
 from . import stockham
@@ -47,7 +48,7 @@ from .reference import _canonical
 
 #: Padded-length thresholds for ``engine="auto"``: the fused single-kernel
 #: Stockham path up to its useful VMEM batch-tile budget, the six-step
-#: composition beyond, the staged jnp fallback past the six-step cap.
+#: composition beyond; past the six-step cap no fused engine applies.
 PALLAS_SINGLE_MAX_M = 1 << 15
 SIXSTEP_MAX_M = 1 << 24
 
@@ -63,23 +64,28 @@ _TABLES_MAX = 32
 
 
 def resolve_engine(n: int, engine: str = "auto",
-                   interpret: bool = False) -> tuple[str, int]:
+                   interpret: bool | None = None) -> tuple[str, int]:
     """Resolve the ``engine`` knob and the padded length m >= 2n - 1 it
     convolves at.  The mixed-radix kernel accepts any 7-smooth m, so it
-    pads far tighter than the pow2-only engines; under interpret mode
-    (off-TPU conformance runs) "auto" keeps the staged jnp engine, where
-    the Pallas interpreter would be pure overhead — an EXPLICIT engine
-    choice still forces the fused kernels anywhere."""
+    pads far tighter than the pow2-only engines.  ``interpret`` follows
+    :func:`repro.core.device.interpret_mode` (``None``: off the TPU).  In
+    interpret mode (off-TPU conformance runs) "auto" keeps the staged jnp
+    engine, where the Pallas interpreter would be pure overhead; on
+    hardware it takes the fused kernels and never falls back to the jnp
+    engine — a padded length past the six-step cap raises.  An EXPLICIT
+    engine choice forces that engine anywhere."""
     lo = 2 * n - 1
     if engine == "auto":
-        if interpret:
+        if interpret_mode(interpret):
             engine = "stockham"
         elif next_smooth(lo) <= PALLAS_SINGLE_MAX_M:
             engine = "stockham_pallas"
         elif _next_pow2(lo) <= SIXSTEP_MAX_M:
             engine = "sixstep"
         else:
-            engine = "stockham"
+            raise ValueError(
+                f"chirp-Z of n={n} pads to {_next_pow2(lo)}, past the fused "
+                f"engines' cap {SIXSTEP_MAX_M}")
     if engine not in ENGINES:
         raise ValueError(f"chirp engine must be one of {ENGINES}, "
                          f"got {engine!r}")
@@ -128,7 +134,7 @@ def chirp_tables(n: int, m: int, dtype, inverse: bool = False):
     return out
 
 
-def _padded_engine(engine: str, tile_b, interpret: bool):
+def _padded_engine(engine: str, tile_b, interpret: bool | None):
     """cfft(x, inverse=False) used for the two padded length-m transforms
     (``engine`` already resolved by :func:`resolve_engine`)."""
     if engine == "stockham":
@@ -145,7 +151,7 @@ def _padded_engine(engine: str, tile_b, interpret: bool):
 
 
 def fft(x: jnp.ndarray, inverse: bool = False, *, engine: str = "stockham",
-        tile_b: int | None = None, interpret: bool = False) -> jnp.ndarray:
+        tile_b: int | None = None, interpret: bool | None = None) -> jnp.ndarray:
     """Chirp-Z DFT along the last axis; works for ANY length n.
 
     ``engine`` selects the padded pow2 engine ("stockham" keeps the staged

@@ -31,27 +31,17 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.5 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map
+from . import fourstep
+from .nd import _apply_last
 
 
 def _shard_map(body, mesh, in_specs, out_specs):
-    """shard_map with replication checking off when supported: pallas_call
-    has no replication rule, and the planned local engines are Pallas
-    kernels.  Our bodies keep every output dim explicitly sharded or
-    device-invariant, so the check adds nothing here."""
-    import inspect
-    params = inspect.signature(shard_map).parameters
-    for kw in ("check_rep", "check_vma"):
-        if kw in params:
-            return shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **{kw: False})
-    return shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-
-from . import fourstep
-from .nd import _apply_last
+    """shard_map with varying-manual-axes checking off: pallas_call has no
+    such rule, and the planned local engines are Pallas kernels.  Our
+    bodies keep every output dim explicitly sharded or device-invariant,
+    so the check adds nothing here."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 #: A local engine: ``cfft(x, inverse=False)`` transforming the LAST axis —
 #: the same contract ``nd.fftn`` consumes, so the per-shard transforms of a
@@ -77,17 +67,11 @@ def _engines_for(rank: int, engines) -> tuple:
 # ---------------------------------------------------------------------------
 # 1D: distributed four-step
 # ---------------------------------------------------------------------------
-def _axis_size(a):
-    if hasattr(jax.lax, "axis_size"):  # jax >= 0.5
-        return jax.lax.axis_size(a)
-    return jax.lax.psum(1, a)          # jax 0.4.x: constant-folded size
-
-
 def _combined_index(axes: tuple[str, ...]):
     """Row-major device index over one or more mesh axes (static sizes)."""
     idx = jax.lax.axis_index(axes[0])
     for a in axes[1:]:
-        idx = idx * _axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 

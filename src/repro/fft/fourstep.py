@@ -22,6 +22,7 @@ larger n.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from .reference import dft_matrix, twiddles
@@ -29,12 +30,16 @@ from .reference import dft_matrix, twiddles
 # Largest radix handled by a single dense DFT matmul; 128 == MXU tile edge.
 MAX_RADIX = 128
 
+#: The TPU's default f32 matmul is one bf16 pass, far outside the c64 error
+#: bound; HIGHEST keeps the DFT matmuls at full f32.
+_PRECISION = jax.lax.Precision.HIGHEST
+
 
 def _base_dft(x: jnp.ndarray, inverse: bool) -> jnp.ndarray:
     """Direct DFT via one matmul; n <= MAX_RADIX. W is symmetric -> x @ W."""
     n = x.shape[-1]
     w = dft_matrix(n, inverse=inverse, dtype=x.dtype)
-    return x @ w
+    return jnp.matmul(x, w, precision=_PRECISION)
 
 
 def _split(n: int) -> tuple[int, int]:
@@ -60,7 +65,7 @@ def _fft_unnormalized(x: jnp.ndarray, inverse: bool) -> jnp.ndarray:
     a = x.reshape(*batch, n1, n2)
     w1 = dft_matrix(n1, inverse=inverse, dtype=x.dtype)
     # column FFTs: B[k1, j2] = sum_j1 W[k1, j1] A[j1, j2]
-    b = jnp.einsum("kj,...jn->...kn", w1, a)
+    b = jnp.einsum("kj,...jn->...kn", w1, a, precision=_PRECISION)
     c = b * twiddles(n1, n2, inverse=inverse, dtype=x.dtype)
     # row FFTs of length n2 (recursive), batched over k1
     d = _fft_unnormalized(c, inverse)

@@ -65,7 +65,7 @@ def choose_split(n: int, n1: int | None = None) -> tuple[int, int]:
 @functools.partial(jax.jit,
                    static_argnames=("inverse", "n1", "tile_b", "interpret"))
 def fft(x: jnp.ndarray, inverse: bool = False, *, n1: int | None = None,
-        tile_b: int | None = None, interpret: bool = False) -> jnp.ndarray:
+        tile_b: int | None = None, interpret: bool | None = None) -> jnp.ndarray:
     """Six-step FFT along the last axis via the two fused Pallas kernels.
 
     ``n1`` (residual split) and ``tile_b`` (batch tile of both kernels) are

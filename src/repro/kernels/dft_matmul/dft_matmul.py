@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.device import interpret_mode
+
 
 DEFAULT_TILE_B = 256  # 256 rows x 128 cols x 4B x 6 planes ~ 0.8 MB VMEM
 
@@ -33,17 +35,17 @@ def _dft_kernel(xr_ref, xi_ref, wr_ref, wi_ref, yr_ref, yi_ref):
     wr = wr_ref[...]
     wi = wi_ref[...]
     # complex matmul on the MXU; accumulate in the plane dtype (f32, or f64
-    # for complex128 problems — the conformance matrix's 1e-8 double bar)
-    pet = xr.dtype
-    yr_ref[...] = jnp.dot(xr, wr, preferred_element_type=pet) - \
-                  jnp.dot(xi, wi, preferred_element_type=pet)
-    yi_ref[...] = jnp.dot(xr, wi, preferred_element_type=pet) + \
-                  jnp.dot(xi, wr, preferred_element_type=pet)
+    # for complex128 problems — the conformance matrix's 1e-8 double bar).
+    # HIGHEST: the TPU's default f32 matmul is one bf16 pass.
+    dot = functools.partial(jnp.dot, precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=xr.dtype)
+    yr_ref[...] = dot(xr, wr) - dot(xi, wi)
+    yi_ref[...] = dot(xr, wi) + dot(xi, wr)
 
 
 @functools.partial(jax.jit, static_argnames=("tile_b", "interpret"))
 def dft_matmul(xr: jnp.ndarray, xi: jnp.ndarray, wr: jnp.ndarray, wi: jnp.ndarray,
-               *, tile_b: int = DEFAULT_TILE_B, interpret: bool = False):
+               *, tile_b: int = DEFAULT_TILE_B, interpret: bool | None = None):
     """Batched DFT planes (B, n) @ DFT matrix (n, n). B % tile_b may be != 0;
     ops.py pads. n should be a multiple of the 128 lane width for peak MXU
     use (smaller n still correct, just padded by Mosaic)."""
@@ -60,6 +62,6 @@ def dft_matmul(xr: jnp.ndarray, xi: jnp.ndarray, wr: jnp.ndarray, wi: jnp.ndarra
         in_specs=[row_spec, row_spec, mat_spec, mat_spec],
         out_specs=[row_spec, row_spec],
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(xr, xi, wr, wi)
     return yr, yi

@@ -20,7 +20,7 @@ def _pad_rows(a: jnp.ndarray, mult: int) -> jnp.ndarray:
 
 
 @functools.partial(jax.jit, static_argnames=("inverse", "interpret", "tile_b"))
-def dft(x: jnp.ndarray, inverse: bool = False, *, interpret: bool = False,
+def dft(x: jnp.ndarray, inverse: bool = False, *, interpret: bool | None = None,
         tile_b: int = DEFAULT_TILE_B) -> jnp.ndarray:
     """Direct DFT along the last axis via the Pallas MXU kernel.
 

@@ -31,6 +31,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.device import interpret_mode
+
 from ..stockham_pallas.stockham_pallas import apply_stages
 
 DEFAULT_TILE_B = 4
@@ -64,7 +66,7 @@ def fft2_pallas(xr, xi, twr, twi, *, n1: int, n2: int,
                 radices1: tuple[int, ...], radices2: tuple[int, ...],
                 offsets1: tuple[tuple[int, ...], ...],
                 offsets2: tuple[tuple[int, ...], ...], inverse: bool,
-                tile_b: int = DEFAULT_TILE_B, interpret: bool = False):
+                tile_b: int = DEFAULT_TILE_B, interpret: bool | None = None):
     """x planes: (B, n1, n2); returns y planes (B, n1, n2), natural order,
     one HBM read + one HBM write of the signal for the whole 2D transform."""
     b = xr.shape[0]
@@ -84,6 +86,6 @@ def fft2_pallas(xr, xi, twr, twi, *, n1: int, n2: int,
         in_specs=[sig, sig, tw, tw],
         out_specs=[sig, sig],
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(xr, xi, twr, twi)
     return yr, yi

@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from ..stockham_pallas.stockham_pallas import radix_schedule
-from ..stockham_pallas.ops import pack_twiddles
+from ..stockham_pallas.ops import batch_tile, pack_twiddles
 from ..stockham_pallas.ops import default_tile_b as _default_tile_b
 from .fft2_pallas import DEFAULT_TILE_B, fft2_pallas
 
@@ -45,7 +45,7 @@ def default_tile_b(n_elems: int, batch: int, itemsize: int) -> int:
 @functools.partial(jax.jit,
                    static_argnames=("inverse", "tile_b", "radix", "interpret"))
 def fft2(x: jnp.ndarray, inverse: bool = False, *, tile_b: int | None = None,
-         radix: int = 8, interpret: bool = False) -> jnp.ndarray:
+         radix: int = 8, interpret: bool | None = None) -> jnp.ndarray:
     """Fused rank-2 FFT over the last TWO axes via the Pallas kernel.
 
     Power-of-two extents with n1*n2 <= ``MAX_ELEMS``; row stages, in-VMEM
@@ -72,9 +72,8 @@ def fft2(x: jnp.ndarray, inverse: bool = False, *, tile_b: int | None = None,
     batch_shape = x.shape[:-2]
     flat = x.reshape(-1, n1, n2)
     b = flat.shape[0]
-    tile = tile_b if tile_b is not None else default_tile_b(
-        n1 * n2, b, jnp.dtype(real_dtype).itemsize)
-    tile = min(tile, max(1, b))
+    tile = batch_tile(tile_b if tile_b is not None else default_tile_b(
+        n1 * n2, b, jnp.dtype(real_dtype).itemsize), b)
     pad = (-b) % tile
 
     xr = jnp.real(flat).astype(real_dtype)

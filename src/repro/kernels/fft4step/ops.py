@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.fft.reference import dft_matrix, twiddles
+from ..stockham_pallas.ops import batch_tile
 from .fft4step import fft4step, DEFAULT_TILE_B
 
 
@@ -30,7 +31,7 @@ def choose_factors(n: int) -> tuple[int, int]:
 
 
 @functools.partial(jax.jit, static_argnames=("inverse", "interpret", "tile_b"))
-def fft(x: jnp.ndarray, inverse: bool = False, *, interpret: bool = False,
+def fft(x: jnp.ndarray, inverse: bool = False, *, interpret: bool | None = None,
         tile_b: int = DEFAULT_TILE_B) -> jnp.ndarray:
     """Four-step FFT along the last axis via the fused Pallas kernel.
 
@@ -46,7 +47,7 @@ def fft(x: jnp.ndarray, inverse: bool = False, *, interpret: bool = False,
     batch_shape = x.shape[:-1]
     flat = x.reshape(-1, n1, n2)
     b = flat.shape[0]
-    tile = min(tile_b, max(1, b))
+    tile = batch_tile(tile_b, b)
     pad = (-b) % tile
 
     xr = jnp.real(flat).astype(rdt)

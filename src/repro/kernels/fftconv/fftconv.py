@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.device import interpret_mode
+
 
 DEFAULT_TILE_B = 4
 
@@ -70,7 +72,7 @@ def _fftconv_kernel(x_ref, hfr_ref, hfi_ref, wfr_ref, wfi_ref, wir_ref,
 
 @functools.partial(jax.jit, static_argnames=("k", "tile_b", "interpret"))
 def fftconv_kernel(x, hfr, hfi, wfr, wfi, wir, wii, tfr, tfi, tir, tii, *,
-                   k: int, tile_b: int = DEFAULT_TILE_B, interpret: bool = False):
+                   k: int, tile_b: int = DEFAULT_TILE_B, interpret: bool | None = None):
     """x: (C, B, k, k) real; hf*: (C, k, k); returns y (C, B, k, k)."""
     c, b = x.shape[0], x.shape[1]
     tile_b = min(tile_b, b)
@@ -85,5 +87,5 @@ def fftconv_kernel(x, hfr, hfi, wfr, wfi, wir, wii, tfr, tfi, tir, tii, *,
         in_specs=[sig, hspec, hspec] + [mat] * 8,
         out_specs=sig,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, hfr, hfi, wfr, wfi, wir, wii, tfr, tfi, tir, tii)

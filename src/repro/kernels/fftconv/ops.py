@@ -22,7 +22,7 @@ def _next_square_pow2(v: int) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "tile_b"))
-def fftconv(x: jnp.ndarray, h: jnp.ndarray, *, interpret: bool = False,
+def fftconv(x: jnp.ndarray, h: jnp.ndarray, *, interpret: bool | None = None,
             tile_b: int = DEFAULT_TILE_B) -> jnp.ndarray:
     """Causal depthwise convolution via the fused Pallas kernel.
 

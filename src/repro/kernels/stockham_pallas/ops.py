@@ -55,6 +55,16 @@ def pack_twiddles(n: int, radices: tuple[int, ...], inverse: bool,
     return twr, twi, tuple(offsets)
 
 
+def batch_tile(tile: int, batch: int) -> int:
+    """The batch tile a kernel grid may use for ``batch`` rows: the whole
+    batch when ``tile`` covers it, else ``tile`` rounded up to a multiple
+    of 8.  Mosaic refuses a block whose second-to-last dim is neither a
+    multiple of the 8 sublanes nor the whole array dim; the wrappers pad
+    the batch to a multiple of the returned tile."""
+    tile = -(-max(1, tile) // 8) * 8 if tile < batch else batch
+    return max(1, min(tile, batch))
+
+
 def default_tile_b(n: int, batch: int, itemsize: int, *, planes: int = 6,
                    cap: int = 256) -> int:
     """Largest power-of-two batch tile whose working planes fit the VMEM
@@ -70,7 +80,7 @@ def default_tile_b(n: int, batch: int, itemsize: int, *, planes: int = 6,
 @functools.partial(jax.jit,
                    static_argnames=("inverse", "tile_b", "radix", "interpret"))
 def fft(x: jnp.ndarray, inverse: bool = False, *, tile_b: int | None = None,
-        radix: int = 8, interpret: bool = False) -> jnp.ndarray:
+        radix: int = 8, interpret: bool | None = None) -> jnp.ndarray:
     """Fused Stockham FFT along the last axis via the Pallas kernel.
 
     7-smooth (2^a*3^b*5^c*7^d) lengths up to ``MAX_N``; all mixed-radix
@@ -95,9 +105,8 @@ def fft(x: jnp.ndarray, inverse: bool = False, *, tile_b: int | None = None,
     batch_shape = x.shape[:-1]
     flat = x.reshape(-1, n)
     b = flat.shape[0]
-    tile = tile_b if tile_b is not None else default_tile_b(
-        n, b, jnp.dtype(real_dtype).itemsize)
-    tile = min(tile, max(1, b))
+    tile = batch_tile(tile_b if tile_b is not None else default_tile_b(
+        n, b, jnp.dtype(real_dtype).itemsize), b)
     pad = (-b) % tile
 
     xr = jnp.real(flat).astype(real_dtype)

@@ -41,6 +41,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.device import interpret_mode
+
 
 DEFAULT_TILE_B = 8
 
@@ -176,7 +178,7 @@ def _stockham_kernel(xr_ref, xi_ref, twr_ref, twi_ref, yr_ref, yi_ref, *,
                               "tile_b", "interpret"))
 def stockham_pallas(xr, xi, twr, twi, *, n: int, radices: tuple[int, ...],
                     offsets: tuple[tuple[int, ...], ...], inverse: bool,
-                    tile_b: int = DEFAULT_TILE_B, interpret: bool = False):
+                    tile_b: int = DEFAULT_TILE_B, interpret: bool | None = None):
     """x planes: (B, n); returns y planes (B, n), natural order, one HBM
     read + one HBM write of the signal regardless of log2(n)."""
     b = xr.shape[0]
@@ -194,6 +196,6 @@ def stockham_pallas(xr, xi, twr, twi, *, n: int, radices: tuple[int, ...],
         in_specs=[sig, sig, tw, tw],
         out_specs=[sig, sig],
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(xr, xi, twr, twi)
     return yr, yi

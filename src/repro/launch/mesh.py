@@ -11,15 +11,12 @@ to any count — data parallelism over pods, DCN-connected).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:  # jax >= 0.5: explicit Auto axis types keep GSPMD semantics stable
-    from jax.sharding import AxisType
 
-    def _axis_kwargs(n: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n}
-except ImportError:  # jax 0.4.x: meshes are Auto-typed implicitly
-    def _axis_kwargs(n: int) -> dict:
-        return {}
+def _axis_kwargs(n: int) -> dict:
+    """Explicit Auto axis types keep GSPMD semantics stable."""
+    return {"axis_types": (AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -19,11 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.5 exposes shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from .layers import Params, _init, apply_rope, init_dense, dense, rope_table
 
 NEG_INF = -1e30
@@ -193,7 +188,7 @@ def context_parallel_attention(q, k, v, *, mesh, dp, tp: str = "model",
                                  block_q=min(block_q, slab), block_k=block_k,
                                  softmax_scale=softmax_scale, vma=vma)
 
-    out = _shard_map(body, mesh=mesh, in_specs=(qspec, kvspec, kvspec),
+    out = jax.shard_map(body, mesh=mesh, in_specs=(qspec, kvspec, kvspec),
                         out_specs=qspec)(q, k, v)
     return out[:, :sq] if pad else out
 

@@ -23,11 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.5 exposes shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from repro.configs.base import ArchConfig
 from . import attention as A
 from . import layers as L
@@ -290,7 +285,7 @@ class Model:
             aux = jax.lax.pmean(aux, sh.dp) if dp is not None else aux
             return y, aux
 
-        fn = _shard_map(island, mesh=self.mesh,
+        fn = jax.shard_map(island, mesh=self.mesh,
                            in_specs=(wspec, xspec),
                            out_specs=(xspec, P()))
         return fn(p, x)

@@ -32,11 +32,13 @@ ICI_BW = 50e9
 CHIPS = {"16x16": 256, "2x16x16": 512}
 
 #: Per-device (peak FLOP/s, HBM bytes/s) envelopes for the FFT roofline,
-#: keyed by a lowercase prefix of ``jax.Device.device_kind``.  The ``cpu``
-#: entry is a deliberately conservative host envelope (one core's FMA
-#: throughput / dual-channel DRAM) so interpret-mode CI containers still
-#: produce *finite, comparable* fractions; absolute cpu fractions are not
-#: meaningful across hosts, their trajectory on one host is.
+#: keyed by a lowercase prefix of ``jax.Device.device_kind``.  TPU peaks are
+#: Google Cloud's published per-chip figures (v5e: 197 TFLOP/s bf16,
+#: 819 GB/s HBM).  The ``cpu`` entry is a deliberately conservative host
+#: envelope (one core's FMA throughput / dual-channel DRAM) so
+#: interpret-mode CI containers still produce *finite, comparable*
+#: fractions; absolute cpu fractions are not meaningful across hosts, their
+#: trajectory on one host is.
 DEVICE_PEAKS = {
     "cpu": (5.0e10, 2.0e10),
     "tpu v5 lite": (PEAK_FLOPS, HBM_BW),
@@ -48,14 +50,18 @@ DEVICE_PEAKS = {
 
 def device_peaks(device_kind: str | None) -> tuple[float, float]:
     """(peak FLOP/s, HBM bytes/s) for a jax ``device_kind`` string, by
-    longest lowercase-prefix match; unknown kinds fall back to the cpu
-    envelope (finite fractions beat a KeyError in a report path)."""
+    longest lowercase-prefix match.  A kind with no entry raises: a roofline
+    share against some other device's peaks would be a wrong number, not a
+    conservative one."""
     dk = (device_kind or "").lower()
     best = None
     for prefix, peaks in DEVICE_PEAKS.items():
         if dk.startswith(prefix) and (best is None or len(prefix) > best[0]):
             best = (len(prefix), peaks)
-    return best[1] if best else DEVICE_PEAKS["cpu"]
+    if best is None:
+        raise KeyError(f"no roofline peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(DEVICE_PEAKS)}")
+    return best[1]
 
 
 def fft_model_flops(extents, batch: int = 1) -> float:

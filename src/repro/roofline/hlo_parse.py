@@ -52,6 +52,7 @@ _TRIP_RE = re.compile(r"known_trip_count[^0-9]*(\d+)")
 _CALL_RE = re.compile(r"(?:calls=|to_apply=)%?([\w\.\-]+)")
 _BRANCH_RE = re.compile(r"branch_computations=\{([^}]*)\}")
 _CONST_RE = re.compile(r"s32\[\]\s+constant\((\d+)\)")
+_STACK_RE = re.compile(r"stack_frame_id=(\d+)")
 
 
 def _dims_prod(dims: str) -> int:
@@ -214,3 +215,22 @@ def analyze(text: str) -> dict:
         "collective_counts": dict(counts),
         "n_computations": len(comps),
     }
+
+
+def count_source_collectives(text: str, op: str = "all-to-all") -> int:
+    """Collectives of kind ``op`` in compiled HLO text, counted per source
+    operation: the TPU compiler splits a complex collective into one per
+    f32 plane, and the halves share the source op's ``stack_frame_id``."""
+    frames: set[str] = set()
+    n = 0
+    for line in text.splitlines():
+        m = _COLL_RE.search(line)
+        if m is None or m.group(4).removesuffix("-start") != op:
+            continue
+        frame = _STACK_RE.search(line)
+        if frame is None:
+            n += 1
+        elif frame.group(1) not in frames:
+            frames.add(frame.group(1))
+            n += 1
+    return n

@@ -112,10 +112,16 @@ def test_pallas_engine_pads_to_smooth_m_not_pow2():
     assert bluestein.resolve_engine(18432, "stockham_pallas") == \
         ("stockham_pallas", 36864)          # vs pow2 65536: 1.78x tighter
     # auto on hardware takes the fused kernel + smooth pad; interpret mode
-    # (off-TPU conformance) keeps the staged jnp engine
-    assert bluestein.resolve_engine(361, "auto") == ("stockham_pallas", 729)
+    # (off-TPU conformance, the default here) keeps the staged jnp engine
+    assert bluestein.resolve_engine(361, "auto", interpret=False) == \
+        ("stockham_pallas", 729)
     assert bluestein.resolve_engine(361, "auto", interpret=True) == \
         ("stockham", 1024)
+    assert bluestein.resolve_engine(361, "auto") == ("stockham", 1024)
+    # on hardware, past the six-step cap there is no fused engine, and auto
+    # refuses rather than falling back to the staged jnp engine
+    with pytest.raises(ValueError, match="cap"):
+        bluestein.resolve_engine((1 << 23) + 1, "auto", interpret=False)
     # numerics hold at the tighter (non-pow2) padded length
     x = rc((2, 361))
     got = bluestein.fft(jnp.asarray(x), engine="stockham_pallas",

@@ -154,6 +154,18 @@ def test_cli_end_to_end(tmp_path):
     assert "XlaFFT" in data and "execute_forward" in data
 
 
+def test_cli_exits_nonzero_when_a_node_fails(tmp_path):
+    # the suite records the failed node and runs the rest; the exit status
+    # still reports it
+    from repro.core.cli import main
+    out = str(tmp_path / "fail.csv")
+    rc = main(["-e", "100", "64", "--client", "Stockham", "--kinds",
+               "Outplace_Complex", "--precisions", "float", "--reps", "1",
+               "--warmups", "0", "-o", out])
+    assert rc == 1
+    assert "64" in open(out).read()
+
+
 def test_cli_wildcard_and_inplace(tmp_path):
     from repro.core.cli import main
     out = str(tmp_path / "cli2.csv")

@@ -101,9 +101,10 @@ def test_device_peaks_prefix_match():
     assert device_peaks("TPU v4 (4 cores)") == DEVICE_PEAKS["tpu v4"]
     assert device_peaks("TPU v5 lite") == DEVICE_PEAKS["tpu v5 lite"]
     assert device_peaks("cpu") == DEVICE_PEAKS["cpu"]
-    # unknown kinds fall back to the conservative cpu envelope
-    assert device_peaks("NVIDIA H100") == DEVICE_PEAKS["cpu"]
-    assert device_peaks(None) == DEVICE_PEAKS["cpu"]
+    # a kind with no entry is an error, never another device's envelope
+    for kind in ("NVIDIA H100", None, ""):
+        with pytest.raises(KeyError, match="no roofline peaks"):
+            device_peaks(kind)
 
 
 def test_fft_model_flops():

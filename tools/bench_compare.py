@@ -523,7 +523,19 @@ def _run_serve(args) -> int:
 
 def _fan_out_devices(args, device_counts: list[int]) -> int:
     """Run the scaling grid: one subprocess per device count (the XLA host
-    device count is frozen at first jax init), merge into one document."""
+    device count is frozen at first jax init), merge into one document.
+
+    CPU only: the children see ``--xla_force_host_platform_device_count``
+    virtual devices.  On a TPU host every child would claim every chip, so
+    the fan-out refuses there."""
+    from repro.core.device import platform as jax_platform
+
+    if jax_platform() == "tpu":
+        raise SystemExit(
+            "--devices fans out one process per device count over virtual "
+            "CPU devices; on a TPU host each child would claim every chip. "
+            "Run it with JAX_PLATFORMS=cpu; the four-chip slab/pencil path "
+            "is `python chip_smoke.py --chips 4`.")
     merged = {"meta": None, "results": []}
     for n in device_counts:
         fd, out = tempfile.mkstemp(suffix=f".dev{n}.json")
@@ -607,6 +619,8 @@ def main(argv=None) -> int:
                         "fraction) rendered from the written document")
     p.add_argument("--_worker", action="store_true", help=argparse.SUPPRESS)
     args = p.parse_args(argv)
+    from repro.core.device import setup_compile_cache
+    setup_compile_cache()
 
     if args.serve and args.chaos:
         return _run_chaos(args)
