@@ -55,6 +55,7 @@ from ..device import interpret_mode
 from ..plan import (Candidate, Plan, PlanCache, PlanRigor, cached_build,
                     executable_bytes, make_plan)
 from ..registry import register_client
+from ..trace import executable_name, named, span
 from ..wisdom import Wisdom
 from repro.fft import bluestein, fourstep, nd, stockham
 from repro.fft import rfft as rfft_mod
@@ -174,12 +175,14 @@ forward_fn = _forward_fn
 
 def build_forward(problem: Problem, cand: Candidate) -> Callable:
     """jit-compiled forward for planner MEASURE timing."""
-    return jax.jit(_forward_fn(problem, cand))
+    return jax.jit(named(_forward_fn(problem, cand),
+                         executable_name(problem, cand, "fwd")))
 
 
 def build_inverse(problem: Problem, cand: Candidate) -> Callable:
     """jit-compiled inverse (the conformance matrix's roundtrip leg)."""
-    return jax.jit(_inverse_fn(problem, cand))
+    return jax.jit(named(_inverse_fn(problem, cand),
+                         executable_name(problem, cand, "inv")))
 
 
 class JaxFFTClient(FFTClient):
@@ -204,6 +207,8 @@ class JaxFFTClient(FFTClient):
         self._spec = None
         self._fwd = self._inv = None
         self._fwd_compiled = self._inv_compiled = None
+        self._fwd_name = self._inv_name = ""
+        self._seq = 0     # ties a transform's dispatch and sync spans
         self._plan_bytes = 0
 
     # --- memory -----------------------------------------------------------
@@ -313,51 +318,64 @@ class JaxFFTClient(FFTClient):
         return self.plan.source if self.plan is not None else ""
 
     def init_forward(self) -> None:
-        cand = self._select()
+        with span("fft.plan"):
+            cand = self._select()
         if cand is None:
             raise RuntimeError("NULL plan (wisdom miss)")  # fftw semantics
+        name = self._fwd_name = executable_name(self.problem, cand, "fwd")
 
         def build():
             donate = (0,) if self.problem.inplace else ()
-            fn = jax.jit(_forward_fn(self.problem, cand), donate_argnums=donate)
+            fn = jax.jit(named(_forward_fn(self.problem, cand), name),
+                         donate_argnums=donate)
             lowered = fn.lower(jax.ShapeDtypeStruct(self._buf.shape, self._buf.dtype))
             return lowered.compile()
 
-        self._fwd_compiled = cached_build(
-            self.plan_cache, self.cache_events, "init_forward",
-            PlanCache.executable_key(self._device_kind(), self.problem,
-                                     cand, "forward"), build)
+        with span("fft.build", exe=name):
+            self._fwd_compiled = cached_build(
+                self.plan_cache, self.cache_events, "init_forward",
+                PlanCache.executable_key(self._device_kind(), self.problem,
+                                         cand, "forward"), build)
         self._plan_bytes = _plan_bytes(self._fwd_compiled)
 
     def init_inverse(self) -> None:
         cand = self.plan.candidate
+        name = self._inv_name = executable_name(self.problem, cand, "inv")
 
         def build():
             donate = (0,) if self.problem.inplace else ()
-            fn = jax.jit(_inverse_fn(self.problem, cand), donate_argnums=donate)
+            fn = jax.jit(named(_inverse_fn(self.problem, cand), name),
+                         donate_argnums=donate)
             spec_shape = jax.eval_shape(_forward_fn(self.problem, cand),
                                         jax.ShapeDtypeStruct((self.problem.batch, *self.problem.extents),
                                                              self.problem.input_dtype.name))
             return fn.lower(spec_shape).compile()
 
-        self._inv_compiled = cached_build(
-            self.plan_cache, self.cache_events, "init_inverse",
-            PlanCache.executable_key(self._device_kind(), self.problem,
-                                     cand, "inverse"), build)
+        with span("fft.build", exe=name):
+            self._inv_compiled = cached_build(
+                self.plan_cache, self.cache_events, "init_inverse",
+                PlanCache.executable_key(self._device_kind(), self.problem,
+                                         cand, "inverse"), build)
         self._plan_bytes += _plan_bytes(self._inv_compiled)
 
     # --- execution --------------------------------------------------------
     def execute_forward(self) -> None:
-        self._spec = self._fwd_compiled(self._buf)
+        self._seq += 1
+        with span("fft.dispatch", exe=self._fwd_name, seq=self._seq):
+            self._spec = self._fwd_compiled(self._buf)
         if self.problem.inplace:
             self._buf = None  # donated
-        self._spec.block_until_ready()
+        with span("fft.sync", exe=self._fwd_name, seq=self._seq):
+            self._spec.block_until_ready()
 
     def execute_inverse(self) -> None:
-        self._buf = self._inv_compiled(self._spec)
+        self._seq += 1
+        with span("fft.dispatch", exe=self._inv_name, seq=self._seq):
+            self._buf = self._inv_compiled(self._spec)
         if self.problem.inplace:
             self._spec = None
-        self._buf.block_until_ready()
+        with span("fft.sync", exe=self._inv_name, seq=self._seq):
+            self._buf.block_until_ready()
 
     # --- transfer ---------------------------------------------------------
     def upload(self, host_data: np.ndarray) -> None:

@@ -25,12 +25,14 @@ production mesh uses ('pod','data','model') so 3D transforms shard over
 from __future__ import annotations
 
 import functools
+import math
 from typing import Sequence
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..core.trace import named
 from . import fourstep
 from .nd import _apply_last
 
@@ -42,6 +44,16 @@ def _shard_map(body, mesh, in_specs, out_specs):
     so the check adds nothing here."""
     return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
+
+
+def _name(decomp: str, mesh_shape, shape, inverse: bool,
+          natural: bool) -> str:
+    """The executable's name: deterministic, so that the persistent compile
+    cache hits in every process."""
+    dims = lambda t: "x".join(str(int(v)) for v in t)
+    return (f"fft_{decomp}{dims(mesh_shape)}_{dims(shape)}_"
+            f"{'nat' if natural else 'tr'}_{'inv' if inverse else 'fwd'}")
+
 
 #: A local engine: ``cfft(x, inverse=False)`` transforming the LAST axis —
 #: the same contract ``nd.fftn`` consumes, so the per-shard transforms of a
@@ -171,7 +183,8 @@ def make_fft1d(mesh: Mesh, axis: str | tuple[str, ...], n: int,
         return out.reshape(-1)
 
     fn = _shard_map(body, mesh, (spec_in,), spec_in)
-    return jax.jit(fn), (n1, n2)
+    return jax.jit(named(fn, _name("dist1d", (p,), (n,), inverse,
+                                   natural))), (n1, n2)
 
 
 def transposed_to_natural(y: jnp.ndarray, n1: int, n2: int) -> jnp.ndarray:
@@ -251,7 +264,8 @@ def make_ifft1d(mesh: Mesh, axis: str | tuple[str, ...], n: int,
         return out.reshape(-1)
 
     fn = _shard_map(body, mesh, (spec,), spec)
-    return jax.jit(fn), (n1, n2)
+    return jax.jit(named(fn, _name("dist1d", (p,), (n,), True,
+                                   natural))), (n1, n2)
 
 
 # ---------------------------------------------------------------------------
@@ -350,7 +364,8 @@ def make_slab_fftnd(mesh: Mesh, axis: str | tuple[str, ...],
         out_spec = slab_spec
 
     fn = _shard_map(body, mesh, (in_spec,), out_spec)
-    return jax.jit(fn), in_spec, out_spec
+    name = _name("slab", (p,), shape, inverse, natural)
+    return jax.jit(named(fn, name)), in_spec, out_spec
 
 
 def make_pencil_fftnd(mesh: Mesh, row_axis, col_axis, shape: Sequence[int],
@@ -427,7 +442,8 @@ def make_pencil_fftnd(mesh: Mesh, row_axis, col_axis, shape: Sequence[int],
         out_spec = pencil_spec
 
     fn = _shard_map(body, mesh, (in_spec,), out_spec)
-    return jax.jit(fn), in_spec, out_spec
+    name = _name("pencil", (pr, pc), shape, inverse, natural)
+    return jax.jit(named(fn, name)), in_spec, out_spec
 
 
 # ---------------------------------------------------------------------------
@@ -482,7 +498,11 @@ def make_fft3d(mesh: Mesh, row_axis, col_axis, shape: Sequence[int],
     in_spec = P(row_t, col_t, None)
     out_spec = P(None, row_t, col_t) if keep_transposed else in_spec
     fn = _shard_map(body, mesh, (in_spec,), out_spec)
-    return jax.jit(fn)
+    size = lambda t: math.prod(mesh.shape[a] for a in
+                               ((t,) if isinstance(t, str) else t))
+    name = _name("fft3d", (size(row_t), size(col_t)), shape, inverse,
+                 not keep_transposed)
+    return jax.jit(named(fn, name))
 
 
 def sharding_for(mesh: Mesh, spec: P) -> NamedSharding:

@@ -727,6 +727,7 @@ class FFTService:
         to the next, and the terminal candidate is tried regardless."""
         import jax
         from ..core.clients.jax_fft import forward_fn
+        from ..core.trace import executable_name, named
 
         problem = Problem(batch.extents, batch.kind, batch.precision,
                           batch=bucket)
@@ -751,7 +752,8 @@ class FFTService:
                 # For r2c the real input can never back the complex output,
                 # and donating it just emits a warning per compile.
                 donate = (0,) if problem.complex_input else ()
-                fn = jax.jit(forward_fn(problem, cand),
+                fn = jax.jit(named(forward_fn(problem, cand),
+                                   executable_name(problem, cand, "fwd")),
                              donate_argnums=donate)
                 spec = jax.ShapeDtypeStruct((bucket, *batch.extents),
                                             problem.input_dtype.name)
