@@ -83,6 +83,14 @@ def _smooth7(n: int) -> bool:
 
 #: Feasibility caps for the fused kernel paths (see the kernel modules).
 FOURSTEP_PALLAS_MAX_N = 128 * 128        # one fused four-step kernel pass
+#: Largest direct (dense-matrix) DFT.  At n <= 384 every split n = n1*n2
+#: leaves a factor of 19 or less, so the four-step kernel's per-signal
+#: matmuls fill a sliver of the 128x128 MXU, while the dense DFT's one
+#: (TILE_B, n) @ (n, n) matmul is up to three lane tiles wide.  VMEM at
+#: TILE_B = 256, n = 384, double-buffered: x/y planes 3 MB + W planes
+#: 2.4 MB, about 5.4 MB of v5e's 16 MiB scoped VMEM.  512 (the accfft
+#: cell's local length) stays on fourstep_pallas until a cell measures it.
+DFT_MAX_N = 384
 STOCKHAM_PALLAS_MAX_N = 1 << 20          # ops.MAX_N: single-kernel hard cap
 STOCKHAM_PALLAS_VMEM_N = 1 << 15         # fits a useful batch tile in VMEM
 SIXSTEP_MIN_N, SIXSTEP_MAX_N = 4, 1 << 24
@@ -155,7 +163,7 @@ def axis_feasible(backend: str, n: int, precision: str = "float") -> bool:
     if backend == "fourstep":
         return _smooth(n)
     if backend == "dft":
-        return n <= 128
+        return n <= DFT_MAX_N
     if backend == "fourstep_pallas":
         return _kernel_factorable(n)
     if backend == "stockham_pallas":

@@ -35,8 +35,8 @@ from typing import Optional
 
 from .client import Problem
 from .candidates import (BACKENDS, CHIRPZ_PALLAS_MAX_N, Candidate,
-                         DIST_A2A_COUNT, DIST_BACKENDS, DIST_NATURAL_EXTRA,
-                         FUSED_ND, FFT2_PALLAS_VMEM_ELEMS,
+                         DFT_MAX_N, DIST_A2A_COUNT, DIST_BACKENDS,
+                         DIST_NATURAL_EXTRA, FUSED_ND, FFT2_PALLAS_VMEM_ELEMS,
                          SIXSTEP_MAX_N, SIXSTEP_MIN_N,
                          STOCKHAM_PALLAS_VMEM_N, _kernel_factorable, _pow2,
                          _smooth, _smooth7, axis_engine_n, axis_feasible,
@@ -98,6 +98,10 @@ class CostCoefficients:
     dft_passes: float = 1.0
     # fused kernels: read + write the signal exactly once
     fourstep_pallas_passes: float = 1.0
+    # fourstep_pallas at n <= DFT_MAX_N, where one factor is 19 or less: on
+    # a v5e its factor-19 kernel ran ~43x one HBM pass's time against ~8x
+    # for the 64x64 kernel charged 1.0 above (361 vs 4096), about 5x
+    fourstep_pallas_narrow_passes: float = 5.0
     stockham_pallas_passes: float = 1.0
     # 2 fused kernel passes + 3 transpose passes
     sixstep_passes: float = 5.0
@@ -145,7 +149,8 @@ BACKEND_COEFFS = {
     "stockham": ("stockham_stage_passes",),
     "fourstep": ("fourstep_level_passes",),
     "dft": ("dft_passes",),
-    "fourstep_pallas": ("fourstep_pallas_passes",),
+    "fourstep_pallas": ("fourstep_pallas_passes",
+                        "fourstep_pallas_narrow_passes"),
     "stockham_pallas": ("stockham_pallas_passes",),
     "sixstep": ("sixstep_passes",),
     "chirpz_pallas": ("chirpz_smooth_passes", "chirpz_pow2_passes"),
@@ -218,9 +223,13 @@ class CostModel:
                 levels += 1
             return c.fourstep_level_passes * levels
         if backend == "dft":
-            return c.dft_passes if n <= 128 else inf
+            return c.dft_passes if n <= DFT_MAX_N else inf
         if backend == "fourstep_pallas":
-            return c.fourstep_pallas_passes if _kernel_factorable(n) else inf
+            if not _kernel_factorable(n):
+                return inf
+            if n <= DFT_MAX_N:
+                return c.fourstep_pallas_narrow_passes
+            return c.fourstep_pallas_passes
         if backend == "stockham_pallas":
             # any 7-smooth length is one mixed-radix kernel pass; beyond the
             # VMEM tile budget the kernel can't hold a batch row

@@ -1,9 +1,12 @@
 """Pallas TPU kernel: batched small-n DFT as a dense MXU matmul.
 
 The TPU-native base case of the four-step decomposition (DESIGN.md §2): an
-n-point DFT with n <= 128 is a single (B_tile, n) x (n, n) matmul against the
-DFT matrix — systolic-array work at full MXU utilization, vs. a butterfly
-chain that would run on the VPU and be bound by VMEM shuffles.
+n-point DFT with n <= 384 (the planner's ``DFT_MAX_N``) is a single
+(B_tile, n) x (n, n) matmul against the DFT matrix — up to three lane tiles
+of systolic-array work, vs. a butterfly chain that would run on the VPU and
+be bound by VMEM shuffles, or a four-step split whose factor of 19 or less
+leaves the MXU nearly empty.  An n that is not a multiple of 128 (361 =
+19^2) is the full array dimension of every block, which Mosaic accepts.
 
 Complex data is carried as separate real/imag f32 planes (Pallas TPU has no
 complex dtype); one complex matmul = 4 real matmuls fused in one kernel pass
@@ -26,7 +29,9 @@ from jax.experimental import pallas as pl
 from repro.core.device import interpret_mode
 
 
-DEFAULT_TILE_B = 256  # 256 rows x 128 cols x 4B x 6 planes ~ 0.8 MB VMEM
+# VMEM at n = 384, double-buffered: x/y planes 4 x 256 x 384 x 4 B x 2 =
+# 3 MB, W planes 2 x 384 x 384 x 4 B x 2 = 2.4 MB; v5e scopes 16 MiB
+DEFAULT_TILE_B = 256
 
 
 def _dft_kernel(xr_ref, xi_ref, wr_ref, wi_ref, yr_ref, yi_ref):
@@ -46,9 +51,9 @@ def _dft_kernel(xr_ref, xi_ref, wr_ref, wi_ref, yr_ref, yi_ref):
 @functools.partial(jax.jit, static_argnames=("tile_b", "interpret"))
 def dft_matmul(xr: jnp.ndarray, xi: jnp.ndarray, wr: jnp.ndarray, wi: jnp.ndarray,
                *, tile_b: int = DEFAULT_TILE_B, interpret: bool | None = None):
-    """Batched DFT planes (B, n) @ DFT matrix (n, n). B % tile_b may be != 0;
-    ops.py pads. n should be a multiple of the 128 lane width for peak MXU
-    use (smaller n still correct, just padded by Mosaic)."""
+    """Batched DFT planes (B, n) @ DFT matrix (n, n), n <= 384. B %
+    tile_b may be != 0; ops.py pads. An n that is not a multiple of the 128
+    lane width runs on lane tiles that Mosaic pads."""
     b, n = xr.shape
     tile_b = min(tile_b, b)
     assert b % tile_b == 0, f"batch {b} not divisible by tile {tile_b}"

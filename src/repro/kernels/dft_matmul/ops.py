@@ -24,7 +24,8 @@ def dft(x: jnp.ndarray, inverse: bool = False, *, interpret: bool | None = None,
         tile_b: int = DEFAULT_TILE_B) -> jnp.ndarray:
     """Direct DFT along the last axis via the Pallas MXU kernel.
 
-    x: complex, any batch shape, last-axis length n <= 128 recommended.
+    x: complex, any batch shape, last-axis length n <= 384 (the planner's
+    ``DFT_MAX_N``: the VMEM budget of one batch tile and its matrix).
     Forward unnormalized, inverse 1/n (numpy semantics).
     """
     if not jnp.issubdtype(x.dtype, jnp.complexfloating):

@@ -7,8 +7,11 @@ test_dist_planner (every ESTIMATE pick and dist crossover is pinned there);
 this file covers the new surface the plan.py split introduced.
 """
 
+import json
 import math
 import warnings
+from dataclasses import replace
+from pathlib import Path
 
 import pytest
 
@@ -20,6 +23,8 @@ from repro.core.costmodel import (BACKEND_COEFFS, DEFAULT_COEFFICIENTS,
                                   set_active_model, spearman, use_model)
 from repro.core.plan import (Candidate, estimate_bytes_moved, estimate_choice,
                              fallback_chain, hbm_passes)
+
+REPO = Path(__file__).resolve().parents[1]
 
 
 # ---------------------------------------------------------------------------
@@ -41,6 +46,33 @@ def test_round_trip_through_dict():
     c = CostCoefficients()
     assert CostCoefficients.from_dict(c.to_dict()) == c
     assert c == DEFAULT_COEFFICIENTS
+
+
+def test_narrow_fourstep_coefficient_round_trips_and_scales():
+    c = replace(CostCoefficients(), fourstep_pallas_narrow_passes=7.5)
+    assert CostCoefficients.from_dict(c.to_dict()) == c
+    # charged above the dense DFT by value, not by enumeration order
+    assert (DEFAULT_COEFFICIENTS.fourstep_pallas_narrow_passes
+            > DEFAULT_COEFFICIENTS.dft_passes)
+    # the fitter scales both fourstep_pallas pass counts together
+    m = DEFAULT_MODEL.scaled({"fourstep_pallas": 2.0})
+    assert m.coeffs.fourstep_pallas_narrow_passes == \
+        2.0 * DEFAULT_COEFFICIENTS.fourstep_pallas_narrow_passes
+    assert m.hbm_passes("fourstep_pallas", 361) == \
+        m.coeffs.fourstep_pallas_narrow_passes
+    assert m.hbm_passes("fourstep_pallas", 4096) == \
+        m.coeffs.fourstep_pallas_passes
+
+
+def test_table_without_narrow_coefficient_loads_its_default():
+    path = REPO / "benchmarks" / "baselines" / "costmodel_cpu.json"
+    doc = json.loads(path.read_text())
+    assert "fourstep_pallas_narrow_passes" not in doc["tables"]["cpu"]
+    cpu = load_tables(str(path))["cpu"]
+    assert cpu.coeffs.fourstep_pallas_narrow_passes == \
+        DEFAULT_COEFFICIENTS.fourstep_pallas_narrow_passes
+    assert cpu.coeffs.fourstep_pallas_passes == \
+        doc["tables"]["cpu"]["fourstep_pallas_passes"]
 
 
 def test_from_dict_warns_on_unknown_coefficient():

@@ -8,6 +8,8 @@ hypothesis = pytest.importorskip(
     "hypothesis", reason="property tests need hypothesis")
 from hypothesis import given, settings, strategies as st  # noqa: E402
 
+from helpers.accuracy import assert_rel_l2
+from repro.fft import nd
 from repro.fft.reference import dft_matrix
 from repro.kernels.dft_matmul.ref import dft_ref
 from repro.kernels.dft_matmul import ops as dft_ops
@@ -59,6 +61,26 @@ def test_dft_ops_matches_numpy(n):
     np.testing.assert_allclose(got, np.fft.fft(x, axis=-1), rtol=1e-3, atol=1e-3)
     got_i = np.asarray(dft_ops.dft(jnp.asarray(x), inverse=True, interpret=True))
     np.testing.assert_allclose(got_i, np.fft.ifft(x, axis=-1), rtol=1e-3, atol=1e-3)
+
+
+@pytest.mark.parametrize("n", [361, 375])
+def test_dft_engine_above_128_through_fftn(n):
+    """The dense DFT at lengths the planner offers it above one lane tile
+    (361 = 19^2, 375 = 3 * 5^3), as the planned path runs it: a rank-3
+    nd.fftn with the dft engine on every axis, forward and inverse."""
+    x = rc((2, n, 3, n))
+    axes = (1, 2, 3)
+
+    def engine(v, inverse=False):
+        return dft_ops.dft(v, inverse=inverse, interpret=True)
+
+    got = np.asarray(nd.fftn(jnp.asarray(x), engine, axes=axes))
+    assert_rel_l2(got, np.fft.fftn(x.astype(np.complex128), axes=axes),
+                  what=f"dft fftn n={n}")
+    got_i = np.asarray(nd.fftn(jnp.asarray(x), engine, axes=axes,
+                               inverse=True))
+    assert_rel_l2(got_i, np.fft.ifftn(x.astype(np.complex128), axes=axes),
+                  what=f"dft ifftn n={n}")
 
 
 # --------------------------------------------------------------------------

@@ -6,10 +6,13 @@ can't silently flip ESTIMATE picks."""
 
 import json
 import math
+from pathlib import Path
 
 import pytest
 
+from repro.core.candidates import DFT_MAX_N
 from repro.core.client import KINDS, PRECISIONS, Problem
+from repro.core.costmodel import dist_local_engine
 from repro.core.plan import (BACKENDS, Candidate, FFT2_PALLAS_MAX_ELEMS,
                              FFT2_PALLAS_VMEM_ELEMS, axis_engine_n,
                              backend_supports, candidates,
@@ -254,3 +257,62 @@ def test_backends_registry_is_complete():
                 seen.add(ax.backend)
     assert seen <= set(BACKENDS) | {"nd"}
     assert set(BACKENDS) <= seen | {"nd"}
+
+
+# --------------------------------------------------------------------------
+# ESTIMATE on the TPU for the chip benchmark's problems
+# --------------------------------------------------------------------------
+TRAFFIC = Path(__file__).resolve().parents[1] / "bench" / "traffic"
+
+#: (mix, extents, batch) -> ESTIMATE's key for (Outplace_Complex,
+#: Outplace_Real) on the TPU.  Only the length-361 problems run the dense
+#: DFT; every other pick is the one the planner made before DFT_MAX_N.
+TPU_PICKS = {
+    ("pow2", "1048576", 64): ("xla", "xla"),
+    ("pow2", "4096x4096", 4): ("xla", "xla"),
+    ("pow2", "256x256x256", 4): ("xla", "xla"),
+    ("pow2", "4096", 16384): ("fourstep_pallas", "fourstep_pallas"),
+    ("pow2", "256x256", 1024): ("xla", "xla"),
+    ("pow2", "128", 262144): ("dft", "dft"),
+    ("nonpow2", "18432", 4096): ("xla", "fourstep_pallas"),
+    ("nonpow2", "6859", 8192): ("xla", "xla"),
+    ("nonpow2", "361x361", 384): ("dft", "dft"),
+    ("nonpow2", "361x361x361", 1): ("dft", "dft"),
+}
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    import repro.core.device as device
+
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+
+
+def test_tpu_picks_cover_the_benchmark_mixes():
+    mixes = {(mix, ext, batch)
+             for mix in ("pow2", "nonpow2")
+             for ext, batch in json.loads(
+                 (TRAFFIC / f"{mix}.json").read_text())["problems"]}
+    assert mixes == set(TPU_PICKS)
+
+
+@pytest.mark.parametrize("mix,ext,batch", sorted(TPU_PICKS),
+                         ids=[f"{m}-{e}" for m, e, _ in sorted(TPU_PICKS)])
+def test_tpu_estimate_picks(on_tpu, mix, ext, batch):
+    extents = tuple(int(v) for v in ext.split("x"))
+    for kind, want in zip(("Outplace_Complex", "Outplace_Real"),
+                          TPU_PICKS[mix, ext, batch]):
+        problem = Problem(extents, kind, "float", batch=batch)
+        assert estimate_choice(problem).key() == want, (kind, want)
+
+
+def test_tpu_dense_dft_cap(on_tpu):
+    """The dense DFT is offered up to DFT_MAX_N and charged less than the
+    four-step kernel there; above it the four-step kernel keeps its one
+    pass, so the four-chip cell's local length 512 is unchanged."""
+    assert backend_supports("dft", Problem((DFT_MAX_N,)))
+    assert not backend_supports("dft", Problem((DFT_MAX_N + 1,)))
+    for n in (361, 375, 384):
+        assert hbm_passes("dft", n) < hbm_passes("fourstep_pallas", n)
+    assert hbm_passes("fourstep_pallas", 512) == 1.0
+    assert dist_local_engine(512) == "fourstep_pallas"

@@ -99,6 +99,8 @@ def test_xla_rejects_c128_on_tpu(one_chip):
     ("Outplace_Complex", (4096,), 16384, "fourstep_pallas"),  # 64 x 64
     ("Outplace_Complex", (3072,), 4, "fourstep_pallas"),      # 48 x 64
     ("Outplace_Real", (18432,), 4096, "fourstep_pallas"),     # packed 96 x 96
+    ("Outplace_Complex", (361, 361, 361), 1, "dft"),          # 361 lanes
+    ("Outplace_Real", (361, 361), 384, "dft"),
 ])
 def test_pallas_kernel_compiles(one_chip, on_tpu, kind, extents, batch,
                                 backend):
