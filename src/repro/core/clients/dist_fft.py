@@ -27,6 +27,7 @@ from ..plan import (Candidate, Plan, PlanCache, PlanRigor, cached_build,
                     dist_local_engine, dist_local_lengths, dist_supports,
                     estimate_bytes_moved, executable_bytes)
 from ..registry import register_client
+from ..trace import dispatch_and_sync, span
 from ..wisdom import Wisdom
 from repro.fft import distributed as dist
 from repro.launch.mesh import flat_mesh, get_active_mesh, reshaped_mesh
@@ -78,7 +79,10 @@ class DistFFT1DClient(FFTClient):
         self._sharding = None
         self._buf = None
         self._spec = None
+        self._local = None   # the local engines, once planned
         self._fwd_compiled = self._inv_compiled = None
+        self._fwd_name = self._inv_name = ""
+        self._seq = 0     # ties a transform's dispatch and sync spans
         self._plan_bytes = 0
 
     # --- memory -----------------------------------------------------------
@@ -110,48 +114,64 @@ class DistFFT1DClient(FFTClient):
     def _n_devices(self) -> int:
         return len(jax.devices())
 
-    def _compile(self, direction: str, build):
+    def _name(self, direction: str) -> str:
+        return dist.executable_name("dist1d", (self._n_devices(),),
+                                    (self._n,), direction == "inverse",
+                                    self._natural)
+
+    def _compile(self, direction: str, name: str, build):
         nat = ",natural" if self._natural else ""
         key = PlanCache.executable_key(
             getattr(self.context, "device_kind", "?"), self.problem,
             f"dist_fourstep[p={self._n_devices()}{nat}]", direction)
-        return cached_build(self.plan_cache, self.cache_events,
-                            f"init_{direction}", key, build)
+        with span("fft.build", exe=name):
+            return cached_build(self.plan_cache, self.cache_events,
+                                f"init_{direction}", key, build)
 
     def _engines(self):
-        cand = Candidate("dist1d", mesh=(self._n_devices(),))
-        return dist_engines(self.problem, cand)
+        """The local engines of the four-step's two passes, chosen once."""
+        if self._local is None:
+            with span("fft.plan"):
+                cand = Candidate("dist1d", mesh=(self._n_devices(),))
+                self._local = dist_engines(self.problem, cand)
+        return self._local
 
     def init_forward(self) -> None:
+        engines = self._engines()
+
         def build():
             fn, _ = dist.make_fft1d(self._mesh, "data", self._n,
-                                    natural=self._natural,
-                                    engines=self._engines())
+                                    natural=self._natural, engines=engines)
             return fn.lower(self._buf).compile()
 
-        self._fwd_compiled = self._compile("forward", build)
+        self._fwd_name = self._name("forward")
+        self._fwd_compiled = self._compile("forward", self._fwd_name, build)
         self._plan_bytes = executable_bytes(self._fwd_compiled)
 
     def init_inverse(self) -> None:
+        engines = self._engines()
+
         def build():
             fn, _ = dist.make_ifft1d(self._mesh, "data", self._n,
-                                     natural=self._natural,
-                                     engines=self._engines())
+                                     natural=self._natural, engines=engines)
             # the spectrum has the signal's shape/dtype/sharding
             return fn.lower(self._spec if self._spec is not None
                             else self._buf).compile()
 
-        self._inv_compiled = self._compile("inverse", build)
+        self._inv_name = self._name("inverse")
+        self._inv_compiled = self._compile("inverse", self._inv_name, build)
         self._plan_bytes += executable_bytes(self._inv_compiled)
 
     # --- execution --------------------------------------------------------
     def execute_forward(self) -> None:
-        self._spec = self._fwd_compiled(self._buf)
-        self._spec.block_until_ready()
+        self._seq += 1
+        self._spec = dispatch_and_sync(self._fwd_name, self._seq,
+                                       self._fwd_compiled, self._buf)
 
     def execute_inverse(self) -> None:
-        self._buf = self._inv_compiled(self._spec)
-        self._buf.block_until_ready()
+        self._seq += 1
+        self._buf = dispatch_and_sync(self._inv_name, self._seq,
+                                      self._inv_compiled, self._spec)
 
     # --- transfer ---------------------------------------------------------
     def upload(self, host_data: np.ndarray) -> None:
@@ -201,6 +221,8 @@ class DistFFTNDClient(FFTClient):
         self._buf = None
         self._spec = None
         self._fwd_compiled = self._inv_compiled = None
+        self._fwd_name = self._inv_name = ""
+        self._seq = 0     # ties a transform's dispatch and sync spans
         self._plan_bytes = 0
 
     # --- planning ---------------------------------------------------------
@@ -260,13 +282,14 @@ class DistFFTNDClient(FFTClient):
     def _select(self) -> Candidate:
         if self.plan is not None:
             return self.plan.candidate
-        if self.plan_cache is not None:
-            pkey = PlanCache.plan_key(
-                getattr(self.context, "device_kind", "?"), self.problem,
-                self.rigor, scope=f"dist[{self._base_mesh.size}]")
-            plan, _ = self.plan_cache.plan(pkey, self._make_plan)
-        else:
-            plan = self._make_plan()
+        with span("fft.plan"):
+            if self.plan_cache is not None:
+                pkey = PlanCache.plan_key(
+                    getattr(self.context, "device_kind", "?"), self.problem,
+                    self.rigor, scope=f"dist[{self._base_mesh.size}]")
+                plan, _ = self.plan_cache.plan(pkey, self._make_plan)
+            else:
+                plan = self._make_plan()
         self.plan = plan
         return plan.candidate
 
@@ -316,14 +339,21 @@ class DistFFTNDClient(FFTClient):
         return self._plan_bytes
 
     # --- compile ----------------------------------------------------------
-    def _compile(self, direction: str, build):
+    def _name(self, direction: str) -> str:
+        cand = self.plan.candidate
+        return dist.executable_name(cand.backend, cand.mesh,
+                                    self.problem.extents,
+                                    direction == "inverse", self._natural)
+
+    def _compile(self, direction: str, name: str, build):
         nat = ",natural" if self._natural else ""
         cand = self.plan.candidate
         key = PlanCache.executable_key(
             getattr(self.context, "device_kind", "?"), self.problem,
             f"{cand.key()}{nat}", direction)
-        return cached_build(self.plan_cache, self.cache_events,
-                            f"init_{direction}", key, build)
+        with span("fft.build", exe=name):
+            return cached_build(self.plan_cache, self.cache_events,
+                                f"init_{direction}", key, build)
 
     def init_forward(self) -> None:
         cand = self._select()
@@ -332,7 +362,8 @@ class DistFFTNDClient(FFTClient):
             fn, _, _, _ = self._build_fn(cand, "forward")
             return fn.lower(self._buf).compile()
 
-        self._fwd_compiled = self._compile("forward", build)
+        self._fwd_name = self._name("forward")
+        self._fwd_compiled = self._compile("forward", self._fwd_name, build)
         self._plan_bytes = executable_bytes(self._fwd_compiled)
 
     def init_inverse(self) -> None:
@@ -347,17 +378,20 @@ class DistFFTNDClient(FFTClient):
                 sharding=NamedSharding(mesh, out_spec))
             return inv.lower(spec_shape).compile()
 
-        self._inv_compiled = self._compile("inverse", build)
+        self._inv_name = self._name("inverse")
+        self._inv_compiled = self._compile("inverse", self._inv_name, build)
         self._plan_bytes += executable_bytes(self._inv_compiled)
 
     # --- execution --------------------------------------------------------
     def execute_forward(self) -> None:
-        self._spec = self._fwd_compiled(self._buf)
-        self._spec.block_until_ready()
+        self._seq += 1
+        self._spec = dispatch_and_sync(self._fwd_name, self._seq,
+                                       self._fwd_compiled, self._buf)
 
     def execute_inverse(self) -> None:
-        self._buf = self._inv_compiled(self._spec)
-        self._buf.block_until_ready()
+        self._seq += 1
+        self._buf = dispatch_and_sync(self._inv_name, self._seq,
+                                      self._inv_compiled, self._spec)
 
     # --- transfer ---------------------------------------------------------
     def upload(self, host_data: np.ndarray) -> None:

@@ -55,7 +55,7 @@ from ..device import interpret_mode
 from ..plan import (Candidate, Plan, PlanCache, PlanRigor, cached_build,
                     executable_bytes, make_plan)
 from ..registry import register_client
-from ..trace import executable_name, named, span
+from ..trace import dispatch_and_sync, executable_name, named, span
 from ..wisdom import Wisdom
 from repro.fft import bluestein, fourstep, nd, stockham
 from repro.fft import rfft as rfft_mod
@@ -361,21 +361,17 @@ class JaxFFTClient(FFTClient):
     # --- execution --------------------------------------------------------
     def execute_forward(self) -> None:
         self._seq += 1
-        with span("fft.dispatch", exe=self._fwd_name, seq=self._seq):
-            self._spec = self._fwd_compiled(self._buf)
+        self._spec = dispatch_and_sync(self._fwd_name, self._seq,
+                                       self._fwd_compiled, self._buf)
         if self.problem.inplace:
             self._buf = None  # donated
-        with span("fft.sync", exe=self._fwd_name, seq=self._seq):
-            self._spec.block_until_ready()
 
     def execute_inverse(self) -> None:
         self._seq += 1
-        with span("fft.dispatch", exe=self._inv_name, seq=self._seq):
-            self._buf = self._inv_compiled(self._spec)
+        self._buf = dispatch_and_sync(self._inv_name, self._seq,
+                                      self._inv_compiled, self._spec)
         if self.problem.inplace:
             self._spec = None
-        with span("fft.sync", exe=self._inv_name, seq=self._seq):
-            self._buf.block_until_ready()
 
     # --- transfer ---------------------------------------------------------
     def upload(self, host_data: np.ndarray) -> None:

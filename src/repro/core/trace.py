@@ -68,6 +68,18 @@ def span(name: str, **attrs):
     return jax.profiler.TraceAnnotation(name, **attrs)
 
 
+def dispatch_and_sync(exe: str, seq: int, fn, arg):
+    """``fn(arg)``, a compiled executable's call, in ``fft.dispatch``, and
+    the wait for its result in ``fft.sync``, both tagged with the
+    executable's name and the client's ordinal ``seq``; returns the
+    result."""
+    with jax.profiler.TraceAnnotation("fft.dispatch", exe=exe, seq=seq):
+        out = fn(arg)
+    with jax.profiler.TraceAnnotation("fft.sync", exe=exe, seq=seq):
+        out.block_until_ready()
+    return out
+
+
 def counters() -> dict[str, tuple[int, float]]:
     """``{span name: (count, seconds)}`` of the ``COUNTED`` spans since the
     last reset."""

@@ -46,10 +46,11 @@ def _shard_map(body, mesh, in_specs, out_specs):
                          out_specs=out_specs, check_vma=False)
 
 
-def _name(decomp: str, mesh_shape, shape, inverse: bool,
-          natural: bool) -> str:
+def executable_name(decomp: str, mesh_shape, shape, inverse: bool,
+                    natural: bool) -> str:
     """The executable's name: deterministic, so that the persistent compile
-    cache hits in every process."""
+    cache hits in every process; the distributed clients tag their spans
+    with it."""
     dims = lambda t: "x".join(str(int(v)) for v in t)
     return (f"fft_{decomp}{dims(mesh_shape)}_{dims(shape)}_"
             f"{'nat' if natural else 'tr'}_{'inv' if inverse else 'fwd'}")
@@ -183,8 +184,8 @@ def make_fft1d(mesh: Mesh, axis: str | tuple[str, ...], n: int,
         return out.reshape(-1)
 
     fn = _shard_map(body, mesh, (spec_in,), spec_in)
-    return jax.jit(named(fn, _name("dist1d", (p,), (n,), inverse,
-                                   natural))), (n1, n2)
+    return jax.jit(named(fn, executable_name("dist1d", (p,), (n,), inverse,
+                                             natural))), (n1, n2)
 
 
 def transposed_to_natural(y: jnp.ndarray, n1: int, n2: int) -> jnp.ndarray:
@@ -264,8 +265,8 @@ def make_ifft1d(mesh: Mesh, axis: str | tuple[str, ...], n: int,
         return out.reshape(-1)
 
     fn = _shard_map(body, mesh, (spec,), spec)
-    return jax.jit(named(fn, _name("dist1d", (p,), (n,), True,
-                                   natural))), (n1, n2)
+    return jax.jit(named(fn, executable_name("dist1d", (p,), (n,), True,
+                                             natural))), (n1, n2)
 
 
 # ---------------------------------------------------------------------------
@@ -364,7 +365,7 @@ def make_slab_fftnd(mesh: Mesh, axis: str | tuple[str, ...],
         out_spec = slab_spec
 
     fn = _shard_map(body, mesh, (in_spec,), out_spec)
-    name = _name("slab", (p,), shape, inverse, natural)
+    name = executable_name("slab", (p,), shape, inverse, natural)
     return jax.jit(named(fn, name)), in_spec, out_spec
 
 
@@ -442,7 +443,7 @@ def make_pencil_fftnd(mesh: Mesh, row_axis, col_axis, shape: Sequence[int],
         out_spec = pencil_spec
 
     fn = _shard_map(body, mesh, (in_spec,), out_spec)
-    name = _name("pencil", (pr, pc), shape, inverse, natural)
+    name = executable_name("pencil", (pr, pc), shape, inverse, natural)
     return jax.jit(named(fn, name)), in_spec, out_spec
 
 
@@ -500,8 +501,8 @@ def make_fft3d(mesh: Mesh, row_axis, col_axis, shape: Sequence[int],
     fn = _shard_map(body, mesh, (in_spec,), out_spec)
     size = lambda t: math.prod(mesh.shape[a] for a in
                                ((t,) if isinstance(t, str) else t))
-    name = _name("fft3d", (size(row_t), size(col_t)), shape, inverse,
-                 not keep_transposed)
+    name = executable_name("fft3d", (size(row_t), size(col_t)), shape,
+                           inverse, not keep_transposed)
     return jax.jit(named(fn, name))
 
 

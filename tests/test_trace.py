@@ -163,3 +163,42 @@ def test_cpu_trace_of_a_planned_client(tmp_path):
             assert ("fft.dispatch", exe, seq) in spans
             assert ("fft.sync", exe, seq) in spans
     assert len(spans) == 4 * len(clients)
+
+
+@pytest.mark.parametrize("backend", ["slab", "pencil", "dist1d"])
+def test_distributed_client_spans(backend, tmp_path):
+    """A distributed client on four fake devices (a subprocess, since a
+    process's device count is fixed when JAX starts): ``fft.plan`` once,
+    ``fft.build`` per executable, the hot-path spans tagged with the
+    executable the device trace names ``jit_<exe>``, and an answer within
+    the AccFFT cell's limits."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"),
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(root, "tests", "helpers",
+                                      "dist_trace_check.py"),
+         backend, str(tmp_path)],
+        env=env, capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    got = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert got["counters"] == {"fft.plan": 1, "fft.build": 2}
+    mesh, extents = {"slab": ("slab4", "32x32x32"),
+                     "pencil": ("pencil2x2", "32x32x32"),
+                     "dist1d": ("dist1d4", "1024")}[backend]
+    fwd, inv = (f"fft_{mesh}_{extents}_tr_{d}" for d in ("fwd", "inv"))
+    assert (got["fwd_name"], got["inv_name"]) == (fwd, inv)
+    assert sorted(map(tuple, got["spans"])) == sorted(
+        [("fft.dispatch", fwd, 1), ("fft.sync", fwd, 1),
+         ("fft.dispatch", inv, 2), ("fft.sync", inv, 2)])
+    assert got["modules"] == sorted(["jit_" + fwd, "jit_" + inv])
+    with open(os.path.join(root, "bench", "configs",
+                           "accfft-c2c-512.json")) as f:
+        limits = json.load(f)["limits"]
+    assert got["fwd_rel_l2"] <= limits["fwd_rel_l2"]
+    assert got["inv_rel_l2"] <= limits["inv_rel_l2"]
