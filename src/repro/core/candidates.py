@@ -83,14 +83,15 @@ def _smooth7(n: int) -> bool:
 
 #: Feasibility caps for the fused kernel paths (see the kernel modules).
 FOURSTEP_PALLAS_MAX_N = 128 * 128        # one fused four-step kernel pass
-#: Largest direct (dense-matrix) DFT.  At n <= 384 every split n = n1*n2
-#: leaves a factor of 19 or less, so the four-step kernel's per-signal
+#: Largest direct (dense-matrix) DFT.  At n <= 512 every split n = n1*n2
+#: leaves a factor of 22 or less, so the four-step kernel's per-signal
 #: matmuls fill a sliver of the 128x128 MXU, while the dense DFT's one
-#: (TILE_B, n) @ (n, n) matmul is up to three lane tiles wide.  VMEM at
-#: TILE_B = 256, n = 384, double-buffered: x/y planes 3 MB + W planes
-#: 2.4 MB, about 5.4 MB of v5e's 16 MiB scoped VMEM.  512 (the accfft
-#: cell's local length) stays on fourstep_pallas until a cell measures it.
-DFT_MAX_N = 384
+#: (TILE_B, n) @ (n, n) matmul is up to four lane tiles wide.  VMEM at
+#: n = 512, double-buffered: W planes 4 MiB, x/y planes 4 MiB at TILE_B
+#: 256, plus HIGHEST's bf16 operand splits: 16.2 MiB, over v5e's 16 MiB
+#: scoped VMEM, so above 384 the kernel takes TILE_B 128
+#: (``dft_matmul.default_tile_b``).
+DFT_MAX_N = 512
 STOCKHAM_PALLAS_MAX_N = 1 << 20          # ops.MAX_N: single-kernel hard cap
 STOCKHAM_PALLAS_VMEM_N = 1 << 15         # fits a useful batch tile in VMEM
 SIXSTEP_MIN_N, SIXSTEP_MAX_N = 4, 1 << 24

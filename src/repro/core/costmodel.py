@@ -98,7 +98,7 @@ class CostCoefficients:
     dft_passes: float = 1.0
     # fused kernels: read + write the signal exactly once
     fourstep_pallas_passes: float = 1.0
-    # fourstep_pallas at n <= DFT_MAX_N, where one factor is 19 or less: on
+    # fourstep_pallas at n <= DFT_MAX_N, where one factor is 22 or less: on
     # a v5e its factor-19 kernel ran ~43x one HBM pass's time against ~8x
     # for the 64x64 kernel charged 1.0 above (361 vs 4096), about 5x
     fourstep_pallas_narrow_passes: float = 5.0

@@ -1,10 +1,10 @@
 """Pallas TPU kernel: batched small-n DFT as a dense MXU matmul.
 
 The TPU-native base case of the four-step decomposition (DESIGN.md §2): an
-n-point DFT with n <= 384 (the planner's ``DFT_MAX_N``) is a single
-(B_tile, n) x (n, n) matmul against the DFT matrix — up to three lane tiles
+n-point DFT with n <= 512 (the planner's ``DFT_MAX_N``) is a single
+(B_tile, n) x (n, n) matmul against the DFT matrix — up to four lane tiles
 of systolic-array work, vs. a butterfly chain that would run on the VPU and
-be bound by VMEM shuffles, or a four-step split whose factor of 19 or less
+be bound by VMEM shuffles, or a four-step split whose factor of 22 or less
 leaves the MXU nearly empty.  An n that is not a multiple of 128 (361 =
 19^2) is the full array dimension of every block, which Mosaic accepts.
 
@@ -32,6 +32,16 @@ from repro.core.device import interpret_mode
 # VMEM at n = 384, double-buffered: x/y planes 4 x 256 x 384 x 4 B x 2 =
 # 3 MB, W planes 2 x 384 x 384 x 4 B x 2 = 2.4 MB; v5e scopes 16 MiB
 DEFAULT_TILE_B = 256
+# Above n = 384 the matrix grows quadratically: at n = 512 and TILE_B 256
+# the planes (4 MiB x/y, 4 MiB W) and HIGHEST's bf16 operand splits need
+# 16.2 MiB, over the 16 MiB limit; TILE_B 128 halves the x/y share.
+WIDE_N = 384
+WIDE_TILE_B = 128
+
+
+def default_tile_b(n: int) -> int:
+    """The batch tile for length-n planes: 256, or 128 above n = 384."""
+    return DEFAULT_TILE_B if n <= WIDE_N else WIDE_TILE_B
 
 
 def _dft_kernel(xr_ref, xi_ref, wr_ref, wi_ref, yr_ref, yi_ref):
@@ -51,7 +61,7 @@ def _dft_kernel(xr_ref, xi_ref, wr_ref, wi_ref, yr_ref, yi_ref):
 @functools.partial(jax.jit, static_argnames=("tile_b", "interpret"))
 def dft_matmul(xr: jnp.ndarray, xi: jnp.ndarray, wr: jnp.ndarray, wi: jnp.ndarray,
                *, tile_b: int = DEFAULT_TILE_B, interpret: bool | None = None):
-    """Batched DFT planes (B, n) @ DFT matrix (n, n), n <= 384. B %
+    """Batched DFT planes (B, n) @ DFT matrix (n, n), n <= 512. B %
     tile_b may be != 0; ops.py pads. An n that is not a multiple of the 128
     lane width runs on lane tiles that Mosaic pads."""
     b, n = xr.shape

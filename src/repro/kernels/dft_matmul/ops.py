@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.fft.reference import dft_matrix
-from .dft_matmul import dft_matmul, DEFAULT_TILE_B
+from .dft_matmul import default_tile_b, dft_matmul
 
 
 def _pad_rows(a: jnp.ndarray, mult: int) -> jnp.ndarray:
@@ -21,11 +21,12 @@ def _pad_rows(a: jnp.ndarray, mult: int) -> jnp.ndarray:
 
 @functools.partial(jax.jit, static_argnames=("inverse", "interpret", "tile_b"))
 def dft(x: jnp.ndarray, inverse: bool = False, *, interpret: bool | None = None,
-        tile_b: int = DEFAULT_TILE_B) -> jnp.ndarray:
+        tile_b: int | None = None) -> jnp.ndarray:
     """Direct DFT along the last axis via the Pallas MXU kernel.
 
-    x: complex, any batch shape, last-axis length n <= 384 (the planner's
+    x: complex, any batch shape, last-axis length n <= 512 (the planner's
     ``DFT_MAX_N``: the VMEM budget of one batch tile and its matrix).
+    ``tile_b`` defaults to :func:`default_tile_b` of n.
     Forward unnormalized, inverse 1/n (numpy semantics).
     """
     if not jnp.issubdtype(x.dtype, jnp.complexfloating):
@@ -42,7 +43,7 @@ def dft(x: jnp.ndarray, inverse: bool = False, *, interpret: bool | None = None,
     wr = jnp.real(w).astype(real_dtype)
     wi = jnp.imag(w).astype(real_dtype)
 
-    tile = min(tile_b, max(8, b))
+    tile = min(tile_b or default_tile_b(n), max(8, b))
     xr = _pad_rows(jnp.real(flat).astype(real_dtype), tile)
     xi = _pad_rows(jnp.imag(flat).astype(real_dtype), tile)
     yr, yi = dft_matmul(xr, xi, wr, wi, tile_b=tile, interpret=interpret)

@@ -38,7 +38,10 @@ def test_classify():
 def test_candidates_feasibility():
     backs = {c.backend for c in candidates(Problem((1024,)))}
     assert {"xla", "stockham", "fourstep", "fourstep_pallas", "bluestein"} <= backs
-    assert "dft" not in backs  # 1024 > 128
+    assert "dft" in backs  # real 1024: the packed engine length is 512
+    backs_c = {c.backend
+               for c in candidates(Problem((1024,), "Outplace_Complex"))}
+    assert "dft" not in backs_c  # 1024 > DFT_MAX_N = 512
     backs_odd = {c.backend for c in candidates(Problem((19 * 19,)))}
     assert "stockham" not in backs_odd and "bluestein" in backs_odd
     backs_tiny = {c.backend for c in candidates(Problem((64,)))}

@@ -83,6 +83,40 @@ def test_dft_engine_above_128_through_fftn(n):
                   what=f"dft ifftn n={n}")
 
 
+@pytest.mark.parametrize("n", [400, 512])
+def test_dft_ops_above_384_matches_numpy(n):
+    """The dense DFT at lengths above 384 (512, the four-chip cell's local
+    length, and 400, not a power of two), forward and inverse, on 300 rows:
+    several 128-row tiles and a padded tail."""
+    x = rc((300, n))
+    got = np.asarray(dft_ops.dft(jnp.asarray(x), interpret=True))
+    assert_rel_l2(got, np.fft.fft(x.astype(np.complex128), axis=-1),
+                  what=f"dft n={n}")
+    got_i = np.asarray(dft_ops.dft(jnp.asarray(x), inverse=True,
+                                   interpret=True))
+    assert_rel_l2(got_i, np.fft.ifft(x.astype(np.complex128), axis=-1),
+                  what=f"idft n={n}")
+
+
+def test_dft_tile_rule():
+    """Lengths up to 384 keep the 256-row tile, and the same program as an
+    explicit tile_b=256; above 384 the tile is 128 rows."""
+    import functools
+
+    import jax
+    from repro.kernels.dft_matmul.dft_matmul import default_tile_b
+
+    assert [default_tile_b(n) for n in (8, 128, 361, 384, 385, 400, 512)] \
+        == [256] * 4 + [128] * 3
+    x = jnp.asarray(rc((600, 361)))
+
+    def jaxpr(tile_b):
+        return str(jax.make_jaxpr(functools.partial(
+            dft_ops.dft, interpret=True, tile_b=tile_b))(x))
+
+    assert jaxpr(None) == jaxpr(256)
+
+
 # --------------------------------------------------------------------------
 # fft4step
 # --------------------------------------------------------------------------

@@ -307,12 +307,36 @@ def test_tpu_estimate_picks(on_tpu, mix, ext, batch):
 
 
 def test_tpu_dense_dft_cap(on_tpu):
-    """The dense DFT is offered up to DFT_MAX_N and charged less than the
-    four-step kernel there; above it the four-step kernel keeps its one
-    pass, so the four-chip cell's local length 512 is unchanged."""
-    assert backend_supports("dft", Problem((DFT_MAX_N,)))
-    assert not backend_supports("dft", Problem((DFT_MAX_N + 1,)))
-    for n in (361, 375, 384):
+    """The dense DFT is offered up to DFT_MAX_N = 512 and charged less than
+    the four-step kernel there, so the four-chip cell's local length 512
+    runs it; 513 is refused."""
+    assert DFT_MAX_N == 512
+    assert backend_supports("dft", Problem((512,)))
+    assert not backend_supports("dft", Problem((513,)))
+    for n in (361, 375, 384, 400, 512):
         assert hbm_passes("dft", n) < hbm_passes("fourstep_pallas", n)
-    assert hbm_passes("fourstep_pallas", 512) == 1.0
-    assert dist_local_engine(512) == "fourstep_pallas"
+    assert dist_local_engine(512) == "dft"
+
+
+class FakeMesh:
+    """Enough mesh for ESTIMATE: candidate enumeration reads ``.size``."""
+    def __init__(self, size: int):
+        self.size = size
+
+
+def test_tpu_estimate_accfft_512_over_four_chips(on_tpu):
+    """The four-chip cell's problem, 512^3 C2C over a flat mesh of four:
+    the distributed client's ESTIMATE picks slab[4], and every local axis
+    of that plan runs the dense DFT."""
+    from repro.core.client import Context
+    from repro.core.clients.dist_fft import DistFFTNDClient
+    from repro.core.plan import dist_local_lengths
+
+    problem = Problem((512, 512, 512), "Outplace_Complex", "float", batch=1)
+    client = DistFFTNDClient(problem, Context())
+    client._base_mesh = FakeMesh(4)
+    cand = client._make_plan().candidate
+    assert cand.key() == "slab[4]"
+    lengths = [n for n, _ in dist_local_lengths(problem, cand)]
+    assert lengths == [512, 512, 512]
+    assert [dist_local_engine(n) for n in lengths] == ["dft"] * 3

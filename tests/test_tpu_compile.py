@@ -101,6 +101,7 @@ def test_xla_rejects_c128_on_tpu(one_chip):
     ("Outplace_Real", (18432,), 4096, "fourstep_pallas"),     # packed 96 x 96
     ("Outplace_Complex", (361, 361, 361), 1, "dft"),          # 361 lanes
     ("Outplace_Real", (361, 361), 384, "dft"),
+    ("Outplace_Complex", (512,), 65536, "dft"),               # 128-row tile
 ])
 def test_pallas_kernel_compiles(one_chip, on_tpu, kind, extents, batch,
                                 backend):

@@ -105,15 +105,15 @@ def test_measurements_includes_scoped_entries(tmp_path):
 # ---------------------------------------------------------------------------
 def test_lookup_near_picks_log2_closest_shape(tmp_path):
     w = _wisdom(tmp_path)
-    for n, backend in ((1024, "stockham_pallas"), (4096, "fourstep_pallas")):
+    for n, backend in ((2048, "stockham_pallas"), (8192, "fourstep_pallas")):
         w.record(Problem((n,), "Outplace_Complex", "float"),
                  Candidate(backend))
-    hit = w.lookup_near(Problem((512,), "Outplace_Complex", "float"))
+    hit = w.lookup_near(Problem((1024,), "Outplace_Complex", "float"))
     assert hit is not None
     cand, neighbor_key = hit
-    # 512 is 1 octave from 1024, 3 from 4096
+    # 1024 is 1 octave from 2048, 3 from 8192 (all above DFT_MAX_N)
     assert cand.backend == "stockham_pallas"
-    assert neighbor_key == "cpu|1024/float/Outplace_Complex/b1"
+    assert neighbor_key == "cpu|2048/float/Outplace_Complex/b1"
 
 
 def test_lookup_near_skips_the_exact_key_and_empty_store(tmp_path):
@@ -192,7 +192,7 @@ def test_lookup_near_scoped_namespaces_are_separate(tmp_path):
     w = _wisdom(tmp_path)
     w.record(Problem((1024,), "Outplace_Complex", "float"),
              Candidate("stockham_pallas"), scope="stockham_pallas")
-    q = Problem((512,), "Outplace_Complex", "float")
+    q = Problem((2048,), "Outplace_Complex", "float")
     assert w.lookup_near(q) is None                        # unscoped view
     assert w.lookup_near(q, scope="stockham_pallas") is not None
 
@@ -204,7 +204,7 @@ def test_make_plan_tags_interpolated_pick_wisdom_near(tmp_path):
     w = _wisdom(tmp_path)
     w.record(Problem((1024,), "Outplace_Complex", "float"),
              Candidate("stockham_pallas"), measured_ms=0.8, rigor="measure")
-    q = Problem((512,), "Outplace_Complex", "float")
+    q = Problem((2048,), "Outplace_Complex", "float")
     plan = make_plan(q, PlanRigor.MEASURE, wisdom=w)
     assert plan.source == "wisdom_near"
     assert plan.candidate.backend == "stockham_pallas"
@@ -221,7 +221,7 @@ def test_make_plan_near_false_disables_interpolation(tmp_path):
     w = _wisdom(tmp_path)
     w.record(Problem((1024,), "Outplace_Complex", "float"),
              Candidate("stockham_pallas"))
-    q = Problem((512,), "Outplace_Complex", "float")
+    q = Problem((2048,), "Outplace_Complex", "float")
     assert make_plan(q, PlanRigor.WISDOM_ONLY, wisdom=w, near=False) is None
     plan = make_plan(q, PlanRigor.MEASURE, wisdom=w, near=False)
     # build-less MEASURE falls through to the estimate pick — and must NOT
