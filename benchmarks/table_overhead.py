@@ -17,7 +17,7 @@ import jax
 from repro.core.benchmark import make_input
 from repro.core.client import Problem
 from repro.core.clients.jax_fft import _forward_fn, _inverse_fn
-from repro.core.plan import Candidate
+from repro.core.candidates import Candidate
 from repro.core.suite import SuiteSpec
 from .common import emit, run_suite
 

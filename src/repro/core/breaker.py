@@ -1,9 +1,8 @@
 """Backend quarantine: a circuit breaker over (backend, problem-class) pairs.
 
-Split out of ``plan.py`` (which re-exports everything here, so existing
-``from repro.core.plan import CircuitBreaker`` imports keep working): the
-breaker is pure fault-tolerance state with no dependency on the candidate
-space or the cost model, and the serve engine imports it on its own.
+The breaker is pure fault-tolerance state with no dependency on the
+candidate space or the cost model: the planner's fallback walk
+(``plan.make_plan``) and the serve engine each keep one.
 """
 
 from __future__ import annotations

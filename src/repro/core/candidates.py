@@ -1,23 +1,25 @@
-"""The planner's candidate space: backends, feasibility, and enumeration.
+"""The planner's candidate space: feasibility on this platform, and
+enumeration.
 
-Split out of ``plan.py`` (which re-exports everything here).  This module
-holds the *structural* half of planning — what a backend can run, which
-(backend, knob) combinations exist for a problem — while the *quantitative*
-half (how many HBM passes each choice costs) lives in
-:mod:`repro.core.costmodel`.  The two layers meet only where enumeration
-prunes by modeled cost: those call sites import the **active** cost model
-lazily, so a fitted per-device coefficient table installed via
-``costmodel.set_active_model`` steers candidate pruning and ranking without
-any caller changing.
+This module holds the *structural* half of planning — what a backend can
+run here, which (backend, knob) combinations exist for a problem — while
+the *quantitative* half (how many HBM passes each choice costs) lives in
+:mod:`repro.core.costmodel`.  What each backend is — its lengths, knobs
+and cost formula — is its record in :mod:`repro.core.backends`.  The two
+layers meet only where enumeration prunes by modeled cost: those call sites
+import the **active** cost model lazily, so a fitted per-device coefficient
+table installed via ``costmodel.set_active_model`` steers candidate pruning
+and ranking without any caller changing.
 """
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Any, Sequence
 
+from .backends import BACKENDS, TABLE, axis_engine_n
 from .client import Problem
-from .extents import (_factors_only, next_pow2 as _next_pow2, next_smooth)
 
 
 @dataclass(frozen=True)
@@ -25,8 +27,8 @@ class Candidate:
     """One point in the planner's search space.
 
     A candidate is either *homogeneous* (one backend applied per axis, or a
-    whole-transform backend from :data:`FUSED_ND`) or — when ``axes`` is
-    non-empty — a **per-axis assignment**: ``axes[i]`` transforms
+    whole-transform backend) or — when ``axes`` is non-empty — a **per-axis
+    assignment**: ``axes[i]`` transforms
     ``extents[i]`` (outermost first), each with its own backend and knobs.
     Per-axis candidates carry the placeholder backend ``'nd'``.
 
@@ -66,72 +68,18 @@ class Candidate:
         return f"{base}({o})" if o else base
 
 
-def _pow2(n: int) -> bool:
-    return n >= 1 and (n & (n - 1)) == 0
-
-
-def _smooth(n: int) -> bool:
-    return n >= 1 and _factors_only(n, (2, 3, 5, 7, 11, 13))
-
-
-def _smooth7(n: int) -> bool:
-    """2^a*3^b*5^c*7^d — the extents the mixed-radix Stockham kernel
-    factors (paper's powerof2 + radix357 classes; shares the extent
-    classifier's ``_factors_only``)."""
-    return n >= 1 and _factors_only(n, (2, 3, 5, 7))
-
-
-#: Feasibility caps for the fused kernel paths (see the kernel modules).
-FOURSTEP_PALLAS_MAX_N = 128 * 128        # one fused four-step kernel pass
-#: Largest direct (dense-matrix) DFT.  At n <= 512 every split n = n1*n2
-#: leaves a factor of 22 or less, so the four-step kernel's per-signal
-#: matmuls fill a sliver of the 128x128 MXU, while the dense DFT's one
-#: (TILE_B, n) @ (n, n) matmul is up to four lane tiles wide.  VMEM at
-#: n = 512, double-buffered: W planes 4 MiB, x/y planes 4 MiB at TILE_B
-#: 256, plus HIGHEST's bf16 operand splits: 16.2 MiB, over v5e's 16 MiB
-#: scoped VMEM, so above 384 the kernel takes TILE_B 128
-#: (``dft_matmul.default_tile_b``).
-DFT_MAX_N = 512
-STOCKHAM_PALLAS_MAX_N = 1 << 20          # ops.MAX_N: single-kernel hard cap
-STOCKHAM_PALLAS_VMEM_N = 1 << 15         # fits a useful batch tile in VMEM
-SIXSTEP_MIN_N, SIXSTEP_MAX_N = 4, 1 << 24
-FFT2_PALLAS_MAX_ELEMS = 1 << 18          # fft2 ops.MAX_ELEMS: hard cap
-FFT2_PALLAS_VMEM_ELEMS = 1 << 16         # n1*n2 tile fits the VMEM budget
-#: Largest chirp-Z length whose padded transform (next_pow2(2n-1)) still
-#: fits the six-step composition's SIXSTEP_MAX_N = 2^24.
-CHIRPZ_PALLAS_MAX_N = 1 << 23
-
-#: Whole-transform backends: one engine call covers every axis, so the
-#: separable path's swapaxes traffic never happens.
-FUSED_ND = ("xla", "fft2_pallas")
-
-#: Every backend the planner knows, in enumeration (preference-tie) order.
-BACKENDS = ("xla", "stockham", "fourstep", "dft", "fourstep_pallas",
-            "stockham_pallas", "sixstep", "fft2_pallas", "chirpz_pallas",
-            "bluestein")
-
-#: Withdrawn on the TPU.  The mixed-radix Stockham stage chain
-#: (``stockham_pallas.apply_stages``) splits the lane axis with
-#: ``x.reshape(*lead, r, m, s)`` and slices twiddles at unaligned static
-#: offsets, which Mosaic refuses ("infer-vector-layout: unsupported shape
-#: cast"); sixstep, fft2_pallas and chirpz_pallas run that chain too.
-#: Until a Mosaic-friendly Stockham exists the planner never offers them
-#: there, so no fallback chain has to hide a failed compile.
-TPU_WITHDRAWN = frozenset({"stockham_pallas", "sixstep", "fft2_pallas",
-                           "chirpz_pallas"})
-
-
 def platform_allows(backend: str, precision: str = "float") -> bool:
     """Can ``backend`` run on this platform at ``precision``?  Everything
-    runs off the TPU (Pallas kernels interpreted).  On the TPU the
-    :data:`TPU_WITHDRAWN` kernels never run, and nothing runs in double:
-    XLA's TPU FFT rejects c128 operands, the TPU compiler aborts on f64
-    matmuls, and Mosaic lowers no f64 planes."""
+    runs off the TPU (Pallas kernels interpreted).  On the TPU the kernels
+    withdrawn there (``Backend.tpu_withdrawn``) never run, and nothing runs
+    in double: XLA's TPU FFT rejects c128 operands, the TPU compiler aborts
+    on f64 matmuls, and Mosaic lowers no f64 planes."""
     from .device import on_tpu
 
     if not on_tpu():
         return True
-    return precision != "double" and backend not in TPU_WITHDRAWN
+    return precision != "double" and not (
+        backend in TABLE and TABLE[backend].tpu_withdrawn)
 
 
 #: Mesh-sharded decompositions (fft/distributed.py) — enumerated only when
@@ -150,83 +98,30 @@ def axis_feasible(backend: str, n: int, precision: str = "float") -> bool:
     """Can ``backend`` transform one batched axis of extent ``n``?  This is
     the engine-level contract: the length the cfft actually receives — n//2
     for the packed r2c innermost axis of an EVEN real extent, the full
-    length for an odd one, see ``axis_engine_n``.  The chirp backends are
-    the any-length catch-all, so odd-length real kinds explicitly route to
-    the full-complex chirp path rather than a meaningless packed half.
-    Backends the platform withdraws (:func:`platform_allows`) are never
-    feasible."""
-    if not platform_allows(backend, precision):
-        return False
-    if backend in ("xla", "bluestein"):
-        return True
-    if backend == "stockham":
-        return _pow2(n)
-    if backend == "fourstep":
-        return _smooth(n)
-    if backend == "dft":
-        return n <= DFT_MAX_N
-    if backend == "fourstep_pallas":
-        return _kernel_factorable(n)
-    if backend == "stockham_pallas":
-        return _smooth7(n) and n <= STOCKHAM_PALLAS_MAX_N
-    if backend == "chirpz_pallas":
-        # any length whose padded pow2 transform the fused engines cover
-        return 1 <= n <= CHIRPZ_PALLAS_MAX_N
-    if backend == "sixstep":
-        # the engine falls back to the fused Stockham kernel below
-        # SIXSTEP_MIN_N (packed-real halves can land there)
-        return _pow2(n) and n <= SIXSTEP_MAX_N and n >= 2
-    return False
-
-
-def axis_engine_n(problem: Problem, axis: int) -> int:
-    """Extent the 1-D engine actually transforms along ``axis``.
-
-    Real kinds take the packed half-length path on the innermost axis (the
-    cfft runs at n//2 for even n; odd lengths pay the full complex
-    transform), so feasibility and the cost model must look at that length,
-    not the nominal extent."""
-    n = problem.extents[axis]
-    if problem.complex_input or axis < problem.rank - 1:
-        return n
-    return n // 2 if n % 2 == 0 and n > 1 else n
-
-
-def fft2_feasible(problem: Problem) -> bool:
-    """The fused rank-2 kernel holds the whole n1 x n2 tile in VMEM."""
-    exts = problem.extents
-    return (len(exts) == 2 and all(_pow2(v) for v in exts)
-            and exts[0] * exts[1] <= FFT2_PALLAS_MAX_ELEMS
-            and (problem.complex_input or exts[-1] % 2 == 0))
+    length for an odd one, see ``axis_engine_n``.  Backends the platform
+    withdraws (:func:`platform_allows`) are never feasible."""
+    rec = TABLE.get(backend)
+    return (rec is not None and platform_allows(backend, precision)
+            and rec.fits(n))
 
 
 def backend_supports(backend: str, problem: Problem) -> bool:
     """Single source of truth for the support matrix: candidates(), the
-    conformance matrix, and the README table all consult this."""
-    if not platform_allows(backend, problem.precision):
+    conformance matrix, and the README table all consult this.  A
+    whole-transform backend answers for the whole problem; a separable one
+    must also fit every axis's engine length."""
+    rec = TABLE.get(backend)
+    if rec is None or not platform_allows(backend, problem.precision):
         return False
-    if backend == "fft2_pallas":
-        return fft2_feasible(problem)
-    if backend == "xla":
-        return True
-    if backend == "sixstep":
-        # offered only where the six-step composition is the real algorithm
-        if not all(_pow2(v) and SIXSTEP_MIN_N <= v <= SIXSTEP_MAX_N
-                   for v in problem.extents):
-            return False
-    return all(axis_feasible(backend, axis_engine_n(problem, i),
-                             problem.precision)
-               for i in range(problem.rank))
+    if rec.rule is not None and not rec.rule(problem):
+        return False
+    return not rec.separable or all(
+        rec.fits(axis_engine_n(problem, i)) for i in range(problem.rank))
 
 
 # ---------------------------------------------------------------------------
 # Distributed candidates: slab / pencil / dist1d over the active mesh
 # ---------------------------------------------------------------------------
-def _mesh_devices(mesh) -> int:
-    """Device count of a mesh (or mesh-shaped stand-in with ``.size``)."""
-    return int(mesh.size)
-
-
 def dist_supports(backend: str, problem: Problem,
                   mesh_shape: Sequence[int]) -> bool:
     """Can ``backend`` decompose ``problem`` over a mesh of ``mesh_shape``?
@@ -242,9 +137,7 @@ def dist_supports(backend: str, problem: Problem,
     from repro.fft import distributed as dist
 
     shape = tuple(int(s) for s in mesh_shape)
-    p = 1
-    for s in shape:
-        p *= s
+    p = math.prod(shape)
     if p < 2:
         return False   # one device: decomposition is pure overhead
     if backend == "dist1d":
@@ -278,9 +171,7 @@ def dist_local_lengths(problem: Problem, cand: Candidate
     shard, each with the swapaxes passes its position costs (+2 when the
     transform axis is not innermost in the local block, like the separable
     single-device path; 0 for the innermost axis)."""
-    p = 1
-    for s in cand.mesh:
-        p *= s
+    p = math.prod(cand.mesh)
     if cand.backend == "dist1d":
         from repro.fft.distributed import _choose_1d_factors
 
@@ -301,7 +192,7 @@ def _dist_candidates(problem: Problem, mesh, patient: bool
     sweeps)."""
     from .costmodel import dist_local_engine, hbm_passes
 
-    p = _mesh_devices(mesh)
+    p = int(mesh.size)   # a mesh, or a stand-in with .size
     if p < 2:
         return []
     out: list[Candidate] = []
@@ -319,7 +210,7 @@ def _dist_candidates(problem: Problem, mesh, patient: bool
             default = {dist_local_engine(n, problem.precision)
                        for n in lengths}
             locals_ = [b for b in BACKENDS
-                       if b not in FUSED_ND and b not in default
+                       if TABLE[b].separable and b not in default
                        and all(axis_feasible(b, n, problem.precision)
                                for n in lengths)
                        and all(hbm_passes(b, n) != float("inf")
@@ -336,10 +227,9 @@ def candidates(problem: Problem, patient: bool = False,
     """Enumerate feasible (backend, knob) combinations for a problem.
 
     The space is ND-native: besides homogeneous candidates (one backend for
-    every axis) it holds the whole-transform backends (``xla``, and the
-    fused rank-2 ``fft2_pallas`` kernel) and **per-axis assignments**
-    (``Candidate.axes``) mixing backends across axes, pruned by the
-    bytes-moved model.  ``patient=True`` widens the space with the fused
+    every axis) it holds the whole-transform backends and **per-axis
+    assignments** (``Candidate.axes``) mixing backends across axes, pruned
+    by the bytes-moved model.  ``patient=True`` widens the space with the fused
     kernels' tunable knobs — batch tiles, the (mixed-)radix schedule, the
     six-step n1*n2 split, the fft2 radix, the chirp-Z padded-engine choice
     — the FFTW_PATIENT analogue of searching algorithm *and* implementation
@@ -350,7 +240,6 @@ def candidates(problem: Problem, patient: bool = False,
     unless a launcher installed one — so single-process planning never
     offers a multi-device plan.
     """
-    exts = problem.extents
     # every backend — the chirp catch-alls included — goes through
     # backend_supports, which evaluates feasibility at the ENGINE length:
     # odd-length real kinds route to the full-complex chirp path (engine
@@ -370,48 +259,10 @@ def candidates(problem: Problem, patient: bool = False,
     if mesh is not None:
         out += _dist_candidates(problem, mesh, patient)
     if patient:
-        extra = []
-        for c in out:
-            if c.options or c.axes:
-                continue
-            if c.backend == "fourstep_pallas":
-                for tb in (4, 8, 16):
-                    extra.append(Candidate("fourstep_pallas", (("tile_b", tb),)))
-            elif c.backend == "stockham_pallas":
-                for tb in (4, 16):
-                    for radix in (4, 8):
-                        extra.append(Candidate(
-                            "stockham_pallas",
-                            (("radix", radix), ("tile_b", tb))))
-            elif c.backend == "sixstep":
-                for n1 in _sixstep_splits(exts[-1]):
-                    extra.append(Candidate("sixstep", (("split_n1", n1),)))
-                extra.append(Candidate("sixstep", (("tile_b", 16),)))
-            elif c.backend == "chirpz_pallas":
-                # a forced engine applies to EVERY axis the separable path
-                # transforms, so gate each knob on every axis's engine
-                # length (_sixstep_splits rule: only emit knobs the engine
-                # actually honors, never ones that raise at build time)
-                eng_ns = [axis_engine_n(problem, i)
-                          for i in range(problem.rank)]
-                engines = []
-                if all(next_smooth(2 * v - 1) <= STOCKHAM_PALLAS_MAX_N
-                       for v in eng_ns):
-                    engines.append("stockham_pallas")  # smooth-m padding
-                if all(SIXSTEP_MIN_N <= _next_pow2(2 * v - 1)
-                       <= SIXSTEP_MAX_N for v in eng_ns):
-                    engines.append("sixstep")
-                for eng in engines:
-                    extra.append(Candidate("chirpz_pallas",
-                                           (("engine", eng),)))
-                extra.append(Candidate("chirpz_pallas", (("tile_b", 16),)))
-            elif c.backend == "fft2_pallas":
-                for tb in (2, 8):
-                    for radix in (4, 8):
-                        extra.append(Candidate(
-                            "fft2_pallas",
-                            (("radix", radix), ("tile_b", tb))))
-        out += extra
+        # each plain candidate's knobs, as its record gives them
+        out += [Candidate(c.backend, opts) for c in out
+                if not (c.options or c.axes) and c.backend in TABLE
+                for opts in TABLE[c.backend].knobs(problem)]
     return out
 
 
@@ -432,7 +283,7 @@ def _mixed_candidates(problem: Problem, limit: int) -> list[Candidate]:
     for i in range(problem.rank):
         n_eng = axis_engine_n(problem, i)
         feas = [b for b in BACKENDS
-                if b not in FUSED_ND
+                if TABLE[b].separable
                 and axis_feasible(b, n_eng, problem.precision)]
         feas.sort(key=lambda b: hbm_passes(b, n_eng))
         per_axis.append(feas[:2])
@@ -446,28 +297,3 @@ def _mixed_candidates(problem: Problem, limit: int) -> list[Candidate]:
             scored.append((cost, cand))
     scored.sort(key=lambda t: t[0])
     return [cand for _, cand in scored[:limit]]
-
-
-def _sixstep_splits(n: int) -> list[int]:
-    """Alternative n = n1*n2 residual splits for the PATIENT sweep: the
-    balanced split and a residual-heavy one, besides the default.  Both
-    sixstep.choose_split constraints apply — n1 <= 2^10 (the residual
-    VMEM cap) and n2 <= 2^14 — so every emitted knob is one the engine
-    actually honors rather than silently replacing with the default."""
-    if not _pow2(n) or n < SIXSTEP_MIN_N:
-        return []
-    k = n.bit_length() - 1
-    default_k1 = k - min(14, k - 1)
-    opts = {max(1, k // 2), max(1, min(10, k - 1))} - {default_k1}
-    return sorted(1 << k1 for k1 in opts
-                  if 1 <= k1 <= 10 and k - k1 <= 14)
-
-
-def _kernel_factorable(n: int) -> bool:
-    """n = n1*n2 with both <= 128 (single fused fft4step kernel pass)."""
-    if n > FOURSTEP_PALLAS_MAX_N:
-        return False
-    for n1 in range(min(128, n), 0, -1):
-        if n % n1 == 0 and n // n1 <= 128:
-            return True
-    return False

@@ -4,7 +4,7 @@ single-device libraries — the FFTW-MPI / cuFFTMp "binaries" of the suite.
 
 ``DistFFT1D`` runs the distributed four-step; ``DistFFTND`` runs the
 planned slab/pencil decompositions, selecting among them (and their local
-per-axis engines) with the interconnect-aware cost model in ``plan.py``.
+per-axis engines) with the interconnect-aware cost model in ``costmodel.py``.
 
 Forward transforms emit the FFTW_MPI_TRANSPOSED_OUT spectrum layout and the
 inverse consumes it directly (TRANSPOSED_IN), so the measured round trip is
@@ -22,10 +22,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..backends import engine
+from ..candidates import Candidate, dist_local_lengths, dist_supports
 from ..client import Context, FFTClient, Problem
-from ..plan import (Candidate, Plan, PlanCache, PlanRigor, cached_build,
-                    dist_local_engine, dist_local_lengths, dist_supports,
-                    estimate_bytes_moved, executable_bytes)
+from ..costmodel import dist_local_engine, estimate_bytes_moved
+from ..plan import (Plan, PlanCache, PlanRigor, cached_build,
+                    executable_bytes)
 from ..registry import register_client
 from ..trace import dispatch_and_sync, span
 from ..wisdom import Wisdom
@@ -37,14 +39,12 @@ def dist_engines(problem: Problem, cand: Candidate) -> list:
     """One local engine per sub-transform of a distributed candidate: the
     ``local`` knob when the sweep forced one, else the cost model's best
     separable backend at each local length — resolved to callables through
-    the same ``_engine`` table every single-device plan uses."""
-    from .jax_fft import _engine
-
+    the same backend table every single-device plan uses."""
     forced = cand.opts().get("local")
     out = []
     for n, _ in dist_local_lengths(problem, cand):
         b = forced or dist_local_engine(n, problem.precision)
-        out.append(_engine(Candidate(b)))
+        out.append(engine(Candidate(b)))
     return out
 
 
@@ -227,7 +227,7 @@ class DistFFTNDClient(FFTClient):
 
     # --- planning ---------------------------------------------------------
     def _candidates(self) -> list[Candidate]:
-        from ..plan import _dist_candidates
+        from ..candidates import _dist_candidates
 
         if self._base_mesh.size < 2:
             # degenerate P=1 mesh: the collectives are identity, the same

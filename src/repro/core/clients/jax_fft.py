@@ -1,29 +1,12 @@
 """The JAX FFT clients — the in-repo analogue of the paper's fftw/cuFFT/clFFT
 client implementations, one per backend engine.
 
-Backend map (DESIGN.md §2):
-  xla              XLA's native FFT HLO ("vendor library", whole-ND)
-  stockham         pure-jnp Stockham autosort (radix-2 butterfly baseline)
-  fourstep         matmul-DFT four-step (MXU formulation, jnp)
-  fourstep_pallas  fused four-step Pallas kernel, n <= 16384
-  stockham_pallas  fused multi-stage Stockham Pallas kernel: every radix
-                   stage on a VMEM-resident batch tile, one HBM touch
-                   (knobs: tile_b, radix)
-  sixstep          large-N path composing stockham_pallas residual
-                   transforms with the fused four-step kernel
-                   (knobs: split_n1, tile_b)
-  fft2_pallas      fused rank-2 kernel: row stages, in-VMEM transpose,
-                   column stages on one resident n1 x n2 tile — the whole
-                   2D transform in one HBM touch (knobs: tile_b, radix)
-  dft              direct matmul DFT Pallas kernel (tiny extents)
-  chirpz_pallas    fused chirp-Z: host-cached chirp + filter spectrum, the
-                   two padded pow2 transforms through the fused Pallas
-                   engines (knobs: engine, tile_b) — the fast oddshape path
-  bluestein        chirp-Z on the staged jnp engine (any size, baseline)
-
-The mixed-radix stockham_pallas kernel covers the paper's radix357 class
-(any 2^a*3^b*5^c*7^d length) in a single HBM touch; chirpz_pallas covers
-oddshape, so all three Fig. 7 extent classes ride fused kernels.
+Each backend — its engine, the lengths it takes, its knobs — is a record
+in :mod:`repro.core.backends`; one client class per backend pins it the way
+a library binary would.  The mixed-radix stockham_pallas kernel covers the
+paper's radix357 class (any 2^a*3^b*5^c*7^d length) in a single HBM touch;
+chirpz_pallas covers oddshape, so all three Fig. 7 extent classes ride fused
+kernels.
 
 Plans are ND-native: a candidate may assign a different backend to every
 axis (``Candidate.axes``); separable engines are applied per axis through
@@ -50,81 +33,16 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..backends import engine
+from ..candidates import Candidate
 from ..client import Context, FFTClient, Problem
-from ..device import interpret_mode
-from ..plan import (Candidate, Plan, PlanCache, PlanRigor, cached_build,
+from ..plan import (Plan, PlanCache, PlanRigor, cached_build,
                     executable_bytes, make_plan)
 from ..registry import register_client
 from ..trace import dispatch_and_sync, executable_name, named, span
 from ..wisdom import Wisdom
-from repro.fft import bluestein, fourstep, nd, stockham
+from repro.fft import nd
 from repro.fft import rfft as rfft_mod
-
-
-def _engine(cand: Candidate) -> Callable:
-    """Return cfft(x, inverse=False) transforming the LAST axis.  Pallas
-    engines get ``interpret`` from :func:`repro.core.device.interpret_mode`
-    (the one platform decision), passed as a concrete value so jit caches
-    never share a trace between interpreted and compiled kernels."""
-    b = cand.backend
-    opts = cand.opts()
-    interp = interpret_mode()
-    if b == "stockham":
-        return stockham.fft
-    if b == "fourstep":
-        return fourstep.fft
-    if b == "bluestein":
-        return bluestein.fft   # staged jnp chirp-Z baseline
-    if b == "chirpz_pallas":
-        engine = opts.get("engine", "auto")
-        tile_b = opts.get("tile_b")
-        return lambda x, inverse=False: bluestein.fft(x, inverse=inverse,
-                                                      engine=engine,
-                                                      tile_b=tile_b,
-                                                      interpret=interp)
-    if b == "fourstep_pallas":
-        from repro.kernels.fft4step import ops as fs_ops
-        tile_b = opts.get("tile_b", 8)
-        return lambda x, inverse=False: fs_ops.fft(x, inverse=inverse,
-                                                   tile_b=tile_b,
-                                                   interpret=interp)
-    if b == "stockham_pallas":
-        from repro.kernels.stockham_pallas import ops as sp_ops
-        tile_b = opts.get("tile_b")
-        radix = opts.get("radix", 8)
-        return lambda x, inverse=False: sp_ops.fft(x, inverse=inverse,
-                                                   tile_b=tile_b, radix=radix,
-                                                   interpret=interp)
-    if b == "sixstep":
-        from repro.fft import sixstep
-        split_n1 = opts.get("split_n1")
-        tile_b = opts.get("tile_b")
-        return lambda x, inverse=False: sixstep.fft(x, inverse=inverse,
-                                                    n1=split_n1, tile_b=tile_b,
-                                                    interpret=interp)
-    if b == "dft":
-        from repro.kernels.dft_matmul import ops as dft_ops
-        return lambda x, inverse=False: dft_ops.dft(x, inverse=inverse,
-                                                    interpret=interp)
-    raise ValueError(f"unknown backend {b!r}")
-
-
-def _fft2_engine(cand: Candidate) -> Callable:
-    """Whole-transform engine cfft2(x, inverse=False) over the LAST TWO
-    axes: the fused rank-2 Pallas kernel."""
-    from repro.kernels.fft2_pallas import ops as f2_ops
-    opts = cand.opts()
-    tile_b = opts.get("tile_b")
-    radix = opts.get("radix", 8)
-    interp = interpret_mode()
-    return lambda x, inverse=False: f2_ops.fft2(x, inverse=inverse,
-                                                tile_b=tile_b, radix=radix,
-                                                interpret=interp)
-
-
-def _axis_engines(problem: Problem, cand: Candidate) -> list[Callable]:
-    """One separable engine per axis from the (possibly per-axis) plan."""
-    return [_engine(c) for c in cand.per_axis(problem.rank)]
 
 
 def _forward_fn(problem: Problem, cand: Candidate) -> Callable:
@@ -137,11 +55,11 @@ def _forward_fn(problem: Problem, cand: Candidate) -> Callable:
         if problem.rank != 2:   # fail loudly, like every other backend's
             raise ValueError(   # infeasible build — never silent wrong math
                 f"fft2_pallas is rank-2 only, got rank {problem.rank}")
-        eng2 = _fft2_engine(cand)
+        eng2 = engine(cand)
         if problem.complex_input:
             return eng2
         return lambda x: rfft_mod.rfftn_packed(x, eng2, rank=2)
-    engines = _axis_engines(problem, cand)
+    engines = [engine(c) for c in cand.per_axis(problem.rank)]
     if problem.complex_input:
         return lambda x: nd.fftn(x, engines, axes=axes)
     return lambda x: nd.rfftn(x, engines, axes=axes)
@@ -157,11 +75,11 @@ def _inverse_fn(problem: Problem, cand: Candidate) -> Callable:
         if problem.rank != 2:
             raise ValueError(
                 f"fft2_pallas is rank-2 only, got rank {problem.rank}")
-        eng2 = _fft2_engine(cand)
+        eng2 = engine(cand)
         if problem.complex_input:
             return lambda y: eng2(y, inverse=True)
         return lambda y: rfft_mod.irfftn_packed(y, problem.extents, eng2)
-    engines = _axis_engines(problem, cand)
+    engines = [engine(c) for c in cand.per_axis(problem.rank)]
     if problem.complex_input:
         return lambda y: nd.fftn(y, engines, axes=axes, inverse=True)
     return lambda y: nd.irfftn(y, problem.extents, engines, axes=axes)
@@ -255,7 +173,8 @@ class JaxFFTClient(FFTClient):
 
     # --- planning ---------------------------------------------------------
     def _make_plan(self) -> Plan | None:
-        from ..plan import candidates, measure_plan
+        from ..candidates import candidates
+        from ..plan import measure_plan
         import time as _time
 
         build = lambda c: build_forward(self.problem, c)
@@ -336,7 +255,7 @@ class JaxFFTClient(FFTClient):
                 self.plan_cache, self.cache_events, "init_forward",
                 PlanCache.executable_key(self._device_kind(), self.problem,
                                          cand, "forward"), build)
-        self._plan_bytes = _plan_bytes(self._fwd_compiled)
+        self._plan_bytes = executable_bytes(self._fwd_compiled)
 
     def init_inverse(self) -> None:
         cand = self.plan.candidate
@@ -356,7 +275,7 @@ class JaxFFTClient(FFTClient):
                 self.plan_cache, self.cache_events, "init_inverse",
                 PlanCache.executable_key(self._device_kind(), self.problem,
                                          cand, "inverse"), build)
-        self._plan_bytes += _plan_bytes(self._inv_compiled)
+        self._plan_bytes += executable_bytes(self._inv_compiled)
 
     # --- execution --------------------------------------------------------
     def execute_forward(self) -> None:
@@ -380,9 +299,6 @@ class JaxFFTClient(FFTClient):
 
     def download(self) -> np.ndarray:
         return np.asarray(self._buf)
-
-
-_plan_bytes = executable_bytes
 
 
 # --- one "binary" per library, as in the paper ------------------------------

@@ -1,12 +1,11 @@
 """The planner's cost model as a first-class, *fittable* layer.
 
-ESTIMATE ranks candidates by modeled HBM traffic (bytes moved).  Before this
-module existed the model's constants — per-backend pass counts, the chirp
-padding overheads, the interconnect link cost — were literals buried in
-``plan.py``: hand-written guesses.  Here they live in a
-:class:`CostCoefficients` table, versioned and loadable per **device kind**,
-so ``tools/fit_costmodel.py`` can regress them from measured BENCH_*.json +
-wisdom data and a Session can install the fitted table for its device.
+ESTIMATE ranks candidates by modeled HBM traffic (bytes moved).  The model's
+constants — per-backend pass counts, the chirp padding overheads, the
+interconnect link cost — live in a :class:`CostCoefficients` table,
+versioned and loadable per **device kind**, so ``tools/fit_costmodel.py``
+can regress them from measured BENCH_*.json + wisdom data and a Session can
+install the fitted table for its device.
 
 Layering:
 
@@ -14,8 +13,10 @@ Layering:
   values **bit-for-bit** — with it installed (the default), every golden
   ESTIMATE pick and dist-cost crossover is byte-identical to the
   pre-refactor planner.
+* Each backend's pass formula is its record's ``passes`` in
+  :mod:`repro.core.backends`; this module sums them over a problem.
 * A module-level *active* model (:func:`get_active_model` /
-  :func:`set_active_model` / :func:`use_model`) is what the compatibility
+  :func:`set_active_model` / :func:`use_model`) is what the module-level
   functions ``hbm_passes`` / ``estimate_bytes_moved`` / ``estimate_choice``
   delegate to; ``plan.fallback_chain`` and the serve engine's chain
   memoization therefore consult fitted rankings the moment a fitted table
@@ -28,21 +29,17 @@ Layering:
 from __future__ import annotations
 
 import json
+import math
 import warnings
 from contextlib import contextmanager
 from dataclasses import dataclass, fields, replace
 from typing import Optional
 
+from .backends import BACKENDS, TABLE, Infeasible, axis_engine_n
+from .candidates import (Candidate, DIST_A2A_COUNT, DIST_BACKENDS,
+                         DIST_NATURAL_EXTRA, axis_feasible, candidates,
+                         dist_local_lengths, dist_supports)
 from .client import Problem
-from .candidates import (BACKENDS, CHIRPZ_PALLAS_MAX_N, Candidate,
-                         DFT_MAX_N, DIST_A2A_COUNT, DIST_BACKENDS,
-                         DIST_NATURAL_EXTRA, FUSED_ND, FFT2_PALLAS_VMEM_ELEMS,
-                         SIXSTEP_MAX_N, SIXSTEP_MIN_N,
-                         STOCKHAM_PALLAS_VMEM_N, _kernel_factorable, _pow2,
-                         _smooth, _smooth7, axis_engine_n, axis_feasible,
-                         candidates, dist_local_lengths, dist_supports,
-                         fft2_feasible)
-from .extents import next_pow2 as _next_pow2, next_smooth
 
 #: Schema stamped into coefficient-table files; loaders reject newer ones.
 COSTMODEL_SCHEMA_VERSION = 1
@@ -56,24 +53,6 @@ DIST_LINK_COST = 4.0
 #: equivalent HBM bytes — keeps tiny transforms from sharding: below ~1 MiB
 #: the collective's constant cost dwarfs any compute win.
 DIST_A2A_LATENCY_BYTES = float(1 << 20)
-
-
-@dataclass(frozen=True)
-class Infeasible:
-    """Typed infeasibility verdict from :meth:`CostModel.estimate`.
-
-    Falsy, and ``float()`` of it is ``inf`` — so numeric callers keep their
-    sentinel while reporting callers (bench_compare's roofline) can tell
-    *why* a row had no modeled traffic instead of silently papering over it.
-    """
-
-    reason: str = ""
-
-    def __bool__(self) -> bool:
-        return False
-
-    def __float__(self) -> float:
-        return float("inf")
 
 
 @dataclass(frozen=True)
@@ -141,23 +120,6 @@ class CostCoefficients:
 
 DEFAULT_COEFFICIENTS = CostCoefficients()
 
-#: Which coefficients a measured row for each backend calibrates — the
-#: fitter scales these together so structural ratios inside a backend
-#: (e.g. chirp smooth vs pow2 overhead) are preserved.
-BACKEND_COEFFS = {
-    "xla": ("xla_smooth_passes", "xla_chirp_passes"),
-    "stockham": ("stockham_stage_passes",),
-    "fourstep": ("fourstep_level_passes",),
-    "dft": ("dft_passes",),
-    "fourstep_pallas": ("fourstep_pallas_passes",
-                        "fourstep_pallas_narrow_passes"),
-    "stockham_pallas": ("stockham_pallas_passes",),
-    "sixstep": ("sixstep_passes",),
-    "chirpz_pallas": ("chirpz_smooth_passes", "chirpz_pow2_passes"),
-    "bluestein": ("bluestein_stage_passes", "bluestein_setup_passes"),
-}
-
-
 class CostModel:
     """Bytes-moved model over one :class:`CostCoefficients` table.
 
@@ -178,11 +140,13 @@ class CostModel:
 
     def scaled(self, backend_scales: dict[str, float],
                device_kind: str = "", source: str = "") -> "CostModel":
-        """A new model with each backend's coefficients (see
-        :data:`BACKEND_COEFFS`) multiplied by its fitted scale."""
+        """A new model with each backend's coefficients (its record's
+        ``coeffs``, scaled together so the structural ratios inside a
+        backend are kept) multiplied by its fitted scale."""
         updates: dict[str, float] = {}
         for backend, scale in backend_scales.items():
-            for name in BACKEND_COEFFS.get(backend, ()):
+            rec = TABLE.get(backend)
+            for name in rec.coeffs if rec else ():
                 updates[name] = getattr(self.coeffs, name) * float(scale)
         return CostModel(replace(self.coeffs, **updates),
                          device_kind or self.device_kind,
@@ -198,68 +162,13 @@ class CostModel:
         and fourstep_pallas read and write the signal exactly once, the
         six-step composition a small constant (2 kernel passes + 3
         transposes), while the staged jnp Stockham pays one pass per
-        radix-2 stage.
+        radix-2 stage.  Each backend's formula is its record's ``passes``,
+        asked only at lengths the record ``fits``.
         """
-        c = self.coeffs
-        inf = float("inf")
-        if backend == "xla":
-            if _smooth7(n):
-                return c.xla_smooth_passes  # vendor path: heavily fused
-            # non-smooth lengths send the vendor library down its own chirp
-            # fallback: ~3 fused transforms at the padded pow2 length
-            return c.xla_chirp_passes * (_next_pow2(2 * n - 1) / n)
-        if backend == "stockham":
-            if not _pow2(n):
-                return inf
-            # one pass per stage
-            return c.stockham_stage_passes * float(max(1, n.bit_length() - 1))
-        if backend == "fourstep":
-            if not _smooth(n):
-                return inf
-            levels = 1
-            m = n
-            while m > 128:
-                m = -(-m // 128)
-                levels += 1
-            return c.fourstep_level_passes * levels
-        if backend == "dft":
-            return c.dft_passes if n <= DFT_MAX_N else inf
-        if backend == "fourstep_pallas":
-            if not _kernel_factorable(n):
-                return inf
-            if n <= DFT_MAX_N:
-                return c.fourstep_pallas_narrow_passes
-            return c.fourstep_pallas_passes
-        if backend == "stockham_pallas":
-            # any 7-smooth length is one mixed-radix kernel pass; beyond the
-            # VMEM tile budget the kernel can't hold a batch row
-            if _smooth7(n) and n <= STOCKHAM_PALLAS_VMEM_N:
-                return c.stockham_pallas_passes
-            return inf
-        if backend == "sixstep":
-            if _pow2(n) and SIXSTEP_MIN_N <= n <= SIXSTEP_MAX_N:
-                return c.sixstep_passes  # 2 fused kernel passes + 3 transposes
-            return inf
-        if backend == "chirpz_pallas":
-            if not 1 <= n <= CHIRPZ_PALLAS_MAX_N:
-                return inf
-            # two fused padded transforms + chirp mul, filter mul, final
-            # chirp; the filter spectrum is host-cached so no third
-            # transform runs.  The mixed-radix kernel convolves at the
-            # smallest 7-SMOOTH m >= 2n-1 (often ~2x tighter than pow2);
-            # sixstep needs pow2.
-            ms = next_smooth(2 * n - 1)
-            if ms <= STOCKHAM_PALLAS_VMEM_N:
-                return c.chirpz_smooth_passes * (ms / n)
-            return c.chirpz_pow2_passes * (_next_pow2(2 * n - 1) / n)
-        if backend == "bluestein":
-            m = 1
-            while m < 2 * n - 1:
-                m *= 2
-            # 3 staged Stockham transforms of padded length m, + chirp setup
-            return (c.bluestein_stage_passes * max(1, m.bit_length() - 1)
-                    + c.bluestein_setup_passes) * (m / n)
-        return inf
+        rec = TABLE.get(backend)
+        if rec is None or not rec.fits(n):
+            return float("inf")
+        return rec.passes(self.coeffs, n)
 
     # --- live elements per axis -------------------------------------------
     @staticmethod
@@ -284,8 +193,8 @@ class CostModel:
         """Modeled HBM bytes for the full nd transform under ``cand``, or a
         typed :class:`Infeasible` verdict.
 
-        Whole-transform backends (``FUSED_ND``) move the signal their fixed
-        number of passes with **no** transpose traffic.  Separable
+        Whole-transform backends move the signal their record's
+        ``whole_passes`` with **no** transpose traffic.  Separable
         assignments charge, per axis: the engine's :meth:`hbm_passes` at the
         extent the engine actually sees (packed half-length on a real
         innermost axis), *plus* the two swapaxes passes ``nd._apply_last``
@@ -305,9 +214,7 @@ class CostModel:
         c = self.coeffs
         complex_itemsize = 16 if problem.precision == "double" else 8
         if cand.backend in DIST_BACKENDS:
-            p = 1
-            for s in cand.mesh:
-                p *= s
+            p = math.prod(cand.mesh)
             if not dist_supports(cand.backend, problem, cand.mesh):
                 return Infeasible(
                     f"{cand.key()} cannot decompose "
@@ -332,25 +239,12 @@ class CostModel:
             return (passes * 2.0 * dev_bytes
                     + n_a2a * (dev_bytes * c.dist_link_cost
                                + c.dist_a2a_latency_bytes))
-        if cand.backend in FUSED_ND:
+        rec = TABLE.get(cand.backend)
+        if rec is not None and not rec.separable:
+            passes = rec.whole_passes(c, problem)
+            if isinstance(passes, Infeasible):
+                return passes
             elems = self.axis_elems(problem, problem.rank - 1)
-            if cand.backend == "xla":
-                # vendor path: 2 fused passes on smooth extents; a
-                # non-smooth axis drags the whole transform into its chirp
-                # fallback
-                passes = max(self.hbm_passes("xla", axis_engine_n(problem, i))
-                             for i in range(problem.rank))
-            else:          # fft2_pallas: one read + one write of the tile
-                # the VMEM budget binds the tile the kernel actually holds:
-                # real kinds run packed, so the inner extent halves (even n)
-                tile_elems = (problem.extents[0] *
-                              axis_engine_n(problem, problem.rank - 1))
-                if not (fft2_feasible(problem)
-                        and tile_elems <= FFT2_PALLAS_VMEM_ELEMS):
-                    return Infeasible(
-                        f"fft2_pallas tile of {tile_elems} elems exceeds "
-                        f"the VMEM budget for {problem.signature()}")
-                passes = 1.0
             return passes * 2.0 * elems * complex_itemsize
         total = 0.0
         for axis, ax_cand in enumerate(cand.per_axis(problem.rank)):
@@ -379,9 +273,7 @@ class CostModel:
         entry."""
         best, best_p = "fourstep", float("inf")
         for b in BACKENDS:
-            if b in FUSED_ND:
-                continue
-            if axis_feasible(b, n, precision):
+            if TABLE[b].separable and axis_feasible(b, n, precision):
                 passes = self.hbm_passes(b, n)
                 if passes < best_p:
                     best, best_p = b, passes
@@ -446,7 +338,7 @@ def use_model(model: Optional[CostModel]):
         set_active_model(prev)
 
 
-# --- compatibility surface (what plan.py re-exports) -----------------------
+# --- the active model's rankings, as module functions -----------------------
 def hbm_passes(backend: str, n: int) -> float:
     return get_active_model().hbm_passes(backend, n)
 
@@ -461,10 +353,6 @@ def estimate_choice(problem: Problem) -> Candidate:
 
 def dist_local_engine(n: int, precision: str = "float") -> str:
     return get_active_model().dist_local_engine(n, precision)
-
-
-def _axis_elems(problem: Problem, axis: int) -> int:
-    return CostModel.axis_elems(problem, axis)
 
 
 # ---------------------------------------------------------------------------

@@ -13,22 +13,13 @@ Planning *time* is a first-class measurement (paper Figs. 4-5: MEASURE costs
 3-4 orders of magnitude more than ESTIMATE and can exceed the transform time
 by far) — the planner therefore reports plan_time_ms with every plan.
 
-This module is the planning *driver* plus the compatibility façade over the
-split-out layers — every historical ``from repro.core.plan import ...``
-keeps resolving:
-
-  :mod:`repro.core.candidates`  the search space: Candidate, feasibility
-                                predicates, backend registries, caps,
-                                candidate enumeration
-  :mod:`repro.core.costmodel`   the fittable bytes-moved model: CostModel,
-                                per-device coefficient tables, hbm_passes /
-                                estimate_bytes_moved / estimate_choice
-  :mod:`repro.core.breaker`     the (backend, problem-class) circuit breaker
-
-The cost functions re-exported here delegate to the **active** cost model
-(:func:`repro.core.costmodel.get_active_model`): installing a fitted
-per-device table re-ranks ESTIMATE picks, fallback chains, and the serve
-engine's chain memoization without any caller changing.
+This module is the planning *driver*.  It plans over
+:mod:`repro.core.candidates` (the search space) and
+:mod:`repro.core.costmodel` (the bytes-moved model), whose module functions
+delegate to the **active** cost model: installing a fitted per-device table
+re-ranks ESTIMATE picks, fallback chains, and the serve engine's chain
+memoization without any caller changing.  What a backend is lives in
+:mod:`repro.core.backends`; the circuit breaker in :mod:`repro.core.breaker`.
 """
 
 from __future__ import annotations
@@ -41,25 +32,10 @@ from typing import Any, Callable, Sequence
 
 import numpy as np
 
+from .breaker import CircuitBreaker, breaker_key
+from .candidates import Candidate, candidates
 from .client import Problem
-
-# --- compatibility façade: the split-out planning layers -------------------
-from .candidates import (  # noqa: F401  (re-exported public surface)
-    BACKENDS, CHIRPZ_PALLAS_MAX_N, Candidate, DIST_A2A_COUNT, DIST_BACKENDS,
-    DIST_NATURAL_EXTRA, FFT2_PALLAS_MAX_ELEMS, FFT2_PALLAS_VMEM_ELEMS,
-    FOURSTEP_PALLAS_MAX_N, FUSED_ND, SIXSTEP_MAX_N, SIXSTEP_MIN_N,
-    STOCKHAM_PALLAS_MAX_N, STOCKHAM_PALLAS_VMEM_N, _dist_candidates,
-    _kernel_factorable, _mesh_devices, _mixed_candidates, _pencil_mesh_shapes,
-    _pow2, _sixstep_splits, _smooth, _smooth7, axis_engine_n, axis_feasible,
-    backend_supports, candidates, dist_local_lengths, dist_supports,
-    fft2_feasible)
-from .costmodel import (  # noqa: F401
-    DIST_A2A_LATENCY_BYTES, DIST_LINK_COST, CostCoefficients, CostModel,
-    Infeasible, _axis_elems, dist_local_engine, estimate_bytes_moved,
-    estimate_choice, get_active_model, hbm_passes, set_active_model,
-    use_model)
-from .breaker import (  # noqa: F401
-    CircuitBreaker, breaker_key, problem_class)
+from .costmodel import estimate_bytes_moved, estimate_choice
 
 
 class PlanRigor(enum.Enum):

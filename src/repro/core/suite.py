@@ -575,7 +575,8 @@ def support_matrix(kinds: Sequence[str] = KINDS,
     (``tests/test_conformance.py`` sweeps exactly the supported cells).
     """
     from .client import Problem
-    from .plan import BACKENDS, backend_supports
+    from .backends import BACKENDS
+    from .candidates import backend_supports
 
     probes = dict(SUPPORT_PROBE_EXTENTS if probes is None else probes)
     rows = []
@@ -604,7 +605,7 @@ def dist_support_matrix(device_counts: Sequence[int] = (2, 4, 8),
     (Pr, Pc) factorization.
     """
     from .client import Problem
-    from .plan import DIST_BACKENDS, _pencil_mesh_shapes, dist_supports
+    from .candidates import DIST_BACKENDS, _pencil_mesh_shapes, dist_supports
 
     probes = dict(SUPPORT_PROBE_EXTENTS if probes is None else probes)
     rows = []

@@ -22,16 +22,14 @@ import time
 
 import jax
 
+from .backends import record
+
 #: Set-up spans, also counted in the table: the plan's selection, and an
 #: executable's build or compile-cache load.
 COUNTED = ("fft.plan", "fft.build")
 #: Every span the program emits: set-up, then the hot path (the call of a
 #: compiled executable, and the wait for its result).
 SPANS = COUNTED + ("fft.dispatch", "fft.sync")
-
-#: Backends that run a Pallas kernel.
-PALLAS = frozenset({"fourstep_pallas", "dft", "stockham_pallas", "sixstep",
-                    "fft2_pallas", "chirpz_pallas"})
 
 _lock = threading.Lock()
 _table: dict[str, list] = {}
@@ -94,11 +92,13 @@ def reset_counters() -> None:
 
 def family(problem, cand) -> str:
     """``xla`` when XLA runs every axis, ``pallas`` when a Pallas kernel
-    runs any axis, ``jnp`` otherwise."""
-    backends = {c.backend for c in cand.per_axis(problem.rank)}
-    if backends == {"xla"}:
+    runs any axis, ``jnp`` otherwise (each backend's record gives its
+    family)."""
+    families = {record(c.backend).family
+                for c in cand.per_axis(problem.rank)}
+    if families == {"xla"}:
         return "xla"
-    return "pallas" if backends & PALLAS else "jnp"
+    return "pallas" if "pallas" in families else "jnp"
 
 
 def executable_name(problem, cand, direction: str) -> str:

@@ -28,8 +28,9 @@ import threading
 import warnings
 from typing import Optional
 
+from .backends import BACKENDS
+from .candidates import Candidate, backend_supports
 from .client import Problem
-from .candidates import (BACKENDS, Candidate, backend_supports)
 from .costmodel import estimate_bytes_moved
 from .extents import classify, parse_extents
 from .breaker import problem_class

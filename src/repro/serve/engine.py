@@ -63,8 +63,9 @@ import numpy as np
 
 from ..core.client import Problem
 from ..core.extents import classify, format_extents, next_pow2
-from ..core.plan import (Candidate, CircuitBreaker, PlanCache, PlanRigor,
-                         breaker_key, fallback_chain, make_plan)
+from ..core.breaker import CircuitBreaker, breaker_key
+from ..core.candidates import Candidate
+from ..core.plan import PlanCache, PlanRigor, fallback_chain, make_plan
 from ..core.results import Row
 from .coalescer import Batch, Coalescer
 from .faults import FaultInjected, FaultPlan, WorkerKilled

@@ -170,8 +170,8 @@ def check_conformance_cells():
     against numpy + inverse roundtrip, exactly like check_cell for the
     single-device backends.  Real kinds must claim nothing."""
     from repro.core.client import KINDS, Problem
-    from repro.core.plan import (Candidate, DIST_BACKENDS,
-                                 _pencil_mesh_shapes, dist_supports)
+    from repro.core.candidates import (Candidate, DIST_BACKENDS,
+                                       _pencil_mesh_shapes, dist_supports)
     from repro.core.clients.dist_fft import dist_engines
 
     p_dev = jax.device_count()

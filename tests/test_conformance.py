@@ -29,7 +29,8 @@ import jax.numpy as jnp
 
 from helpers.accuracy import assert_rel_l2, numpy_forward, rand_input
 from repro.core.client import KINDS, PRECISIONS, Problem
-from repro.core.plan import BACKENDS, Candidate, backend_supports
+from repro.core.backends import BACKENDS
+from repro.core.candidates import Candidate, backend_supports
 from repro.core.suite import SUPPORT_PROBE_EXTENTS, support_matrix
 from repro.core.clients.jax_fft import build_forward, build_inverse
 

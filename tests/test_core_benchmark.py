@@ -6,7 +6,9 @@ import pytest
 from repro.core.benchmark import Benchmark, BenchmarkConfig, make_input, roundtrip_error
 from repro.core.client import Context, Problem
 from repro.core.extents import classify, parse_extents, format_extents
-from repro.core.plan import Candidate, PlanRigor, candidates, estimate_choice, make_plan
+from repro.core.candidates import Candidate, candidates
+from repro.core.costmodel import estimate_choice
+from repro.core.plan import PlanRigor, make_plan
 from repro.core.tree import build_tree, select
 from repro.core.wisdom import Wisdom
 from repro.core.clients import jax_fft as jf

@@ -4,7 +4,7 @@ Spearman metric the fitter/CI assert on.
 
 Golden-value guards live in test_planner_nd / test_planner_autotune /
 test_dist_planner (every ESTIMATE pick and dist crossover is pinned there);
-this file covers the new surface the plan.py split introduced.
+this file covers the cost model's own surface.
 """
 
 import json
@@ -15,14 +15,16 @@ from pathlib import Path
 
 import pytest
 
+from repro.core.backends import TABLE, Infeasible
+from repro.core.candidates import Candidate
 from repro.core.client import Problem
-from repro.core.costmodel import (BACKEND_COEFFS, DEFAULT_COEFFICIENTS,
-                                  DEFAULT_MODEL, CostCoefficients, CostModel,
-                                  Infeasible, get_active_model, load_tables,
+from repro.core.costmodel import (DEFAULT_COEFFICIENTS, DEFAULT_MODEL,
+                                  CostCoefficients, CostModel,
+                                  estimate_bytes_moved, estimate_choice,
+                                  get_active_model, hbm_passes, load_tables,
                                   model_for_device, save_tables,
                                   set_active_model, spearman, use_model)
-from repro.core.plan import (Candidate, estimate_bytes_moved, estimate_choice,
-                             fallback_chain, hbm_passes)
+from repro.core.plan import fallback_chain
 
 REPO = Path(__file__).resolve().parents[1]
 
@@ -111,8 +113,8 @@ def test_scaled_touches_only_the_backend_coefficients():
     m = DEFAULT_MODEL.scaled({"stockham": 3.0}, device_kind="test")
     assert m.coeffs.stockham_stage_passes == 3.0
     # everything outside the stockham group is untouched
-    for name in (f for b, names in BACKEND_COEFFS.items() if b != "stockham"
-                 for f in names):
+    for name in (f for b, rec in TABLE.items() if b != "stockham"
+                 for f in rec.coeffs):
         assert getattr(m.coeffs, name) == getattr(DEFAULT_COEFFICIENTS, name)
     assert m.device_kind == "test"
     # original model unchanged (frozen coefficients)
@@ -290,21 +292,6 @@ def test_roofline_fallback_tags_infeasible_rows():
     assert "roofline_fallback" not in rec2
     assert rec2["model_bytes"] == estimate_bytes_moved(p, Candidate("bluestein"))
     bc.ROOFLINE_FALLBACKS.clear()
-
-
-# ---------------------------------------------------------------------------
-# plan.py facade: the split must keep every historical import working
-# ---------------------------------------------------------------------------
-def test_plan_facade_reexports_the_split_modules():
-    from repro.core import plan as plan_mod
-    for name in ("BACKENDS", "DIST_BACKENDS", "Candidate", "CircuitBreaker",
-                 "DIST_LINK_COST", "Infeasible", "CostModel",
-                 "breaker_key", "problem_class", "candidates",
-                 "backend_supports", "dist_supports", "estimate_choice",
-                 "estimate_bytes_moved", "hbm_passes", "fallback_chain",
-                 "use_model", "get_active_model", "set_active_model",
-                 "_axis_elems", "_mixed_candidates", "_pencil_mesh_shapes"):
-        assert hasattr(plan_mod, name), name
 
 
 def test_deprecated_wisdom_generate_warns():

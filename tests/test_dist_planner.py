@@ -14,9 +14,10 @@ import warnings
 import pytest
 
 from repro.core.client import Problem
-from repro.core.plan import (Candidate, DIST_BACKENDS, _pencil_mesh_shapes,
-                             candidates, dist_local_engine, dist_supports,
-                             estimate_bytes_moved)
+from repro.core.candidates import (Candidate, DIST_BACKENDS,
+                                   _pencil_mesh_shapes, candidates,
+                                   dist_supports)
+from repro.core.costmodel import dist_local_engine, estimate_bytes_moved
 from repro.core.suite import SuiteSpec, dist_support_matrix
 from repro.core.wisdom import Wisdom
 
@@ -176,7 +177,7 @@ def test_infeasible_dist_candidate_costs_inf():
 
 
 def test_dist_local_engine_minimizes_passes():
-    from repro.core.plan import hbm_passes
+    from repro.core.costmodel import hbm_passes
     for n in (16, 64, 512, 4096):
         b = dist_local_engine(n)
         assert hbm_passes(b, n) == min(

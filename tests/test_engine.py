@@ -74,7 +74,7 @@ def test_plan_cache_hit_miss_accounting():
 
 def test_plan_cache_keys_on_device_and_candidate():
     p = Problem((64,))
-    from repro.core.plan import Candidate
+    from repro.core.candidates import Candidate
     k_cpu = PlanCache.executable_key("cpu", p, Candidate("xla"), "forward")
     k_tpu = PlanCache.executable_key("TPU v4", p, Candidate("xla"), "forward")
     k_cand = PlanCache.executable_key("cpu", p, Candidate("stockham"), "forward")
@@ -331,7 +331,7 @@ def test_cli_wisdom_uses_discovered_device_kind(tmp_path):
     never matched stores pre-generated with the real JAX device kind."""
     import jax
     from repro.core.cli import main
-    from repro.core.plan import Candidate
+    from repro.core.candidates import Candidate
 
     wpath = str(tmp_path / "wisdom.json")
     w = Wisdom(wpath, device_kind=jax.devices()[0].device_kind)

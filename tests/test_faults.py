@@ -11,9 +11,9 @@ import numpy as np
 import pytest
 
 from repro.core.client import Problem
-from repro.core.plan import (Candidate, CircuitBreaker, PlanRigor,
-                             breaker_key, fallback_chain, make_plan,
-                             probe_finite, problem_class)
+from repro.core.breaker import CircuitBreaker, breaker_key, problem_class
+from repro.core.candidates import Candidate
+from repro.core.plan import PlanRigor, fallback_chain, make_plan, probe_finite
 from repro.core.wisdom import WISDOM_SCHEMA_VERSION, Wisdom
 from repro.serve import (FaultInjected, FaultPlan, FaultRule, TrafficSpec,
                          faulty_build)

@@ -80,7 +80,7 @@ def test_rejects_non_pow2_and_oversize():
 
 
 def test_cap_matches_planner_constant():
-    from repro.core.plan import FFT2_PALLAS_MAX_ELEMS
+    from repro.core.backends import FFT2_PALLAS_MAX_ELEMS
     assert f2_ops.MAX_ELEMS == FFT2_PALLAS_MAX_ELEMS
 
 
@@ -89,7 +89,7 @@ def test_engine_rejects_wrong_rank_loudly():
     at build time — fft2 over the last two axes of a (batch, n) array would
     transform the batch axis and return correct-shaped wrong math."""
     from repro.core.client import Problem
-    from repro.core.plan import Candidate
+    from repro.core.candidates import Candidate
     from repro.core.clients.jax_fft import build_forward, build_inverse
     for ext in [(1024,), (8, 8, 8)]:
         with pytest.raises(ValueError, match="rank-2 only"):

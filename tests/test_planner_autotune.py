@@ -8,9 +8,11 @@ import math
 import pytest
 
 from repro.core.client import Problem
-from repro.core.plan import (Candidate, PlanRigor, STOCKHAM_PALLAS_VMEM_N,
-                             candidates, estimate_bytes_moved,
-                             estimate_choice, hbm_passes, make_plan)
+from repro.core.backends import STOCKHAM_PALLAS_VMEM_N
+from repro.core.candidates import Candidate, candidates
+from repro.core.costmodel import (estimate_bytes_moved, estimate_choice,
+                                  hbm_passes)
+from repro.core.plan import PlanRigor, make_plan
 from repro.core.suite import Session, SuiteSpec
 from repro.core.wisdom import Wisdom
 from repro.core.clients.jax_fft import build_forward

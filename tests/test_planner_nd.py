@@ -10,14 +10,12 @@ from pathlib import Path
 
 import pytest
 
-from repro.core.candidates import DFT_MAX_N
+from repro.core.backends import (BACKENDS, DFT_MAX_N, FFT2_PALLAS_MAX_ELEMS,
+                                 FFT2_PALLAS_VMEM_ELEMS, axis_engine_n)
+from repro.core.candidates import Candidate, backend_supports, candidates
 from repro.core.client import KINDS, PRECISIONS, Problem
-from repro.core.costmodel import dist_local_engine
-from repro.core.plan import (BACKENDS, Candidate, FFT2_PALLAS_MAX_ELEMS,
-                             FFT2_PALLAS_VMEM_ELEMS, axis_engine_n,
-                             backend_supports, candidates,
-                             estimate_bytes_moved, estimate_choice,
-                             hbm_passes)
+from repro.core.costmodel import (dist_local_engine, estimate_bytes_moved,
+                                  estimate_choice, hbm_passes)
 from repro.core.wisdom import Wisdom
 
 INF = float("inf")
@@ -208,7 +206,7 @@ def test_odd_length_real_kinds_route_to_full_complex_chirp():
     # the chirp candidates enter through backend_supports like everyone
     # else (no unconditional append), so the cap binds at the full length:
     # an odd n past CHIRPZ_PALLAS_MAX_N keeps only the jnp chirp
-    from repro.core.plan import CHIRPZ_PALLAS_MAX_N
+    from repro.core.backends import CHIRPZ_PALLAS_MAX_N
     p_big = Problem(((CHIRPZ_PALLAS_MAX_N + 1),), "Outplace_Real")
     assert not backend_supports("chirpz_pallas", p_big)
     assert backend_supports("bluestein", p_big)
@@ -330,7 +328,7 @@ def test_tpu_estimate_accfft_512_over_four_chips(on_tpu):
     of that plan runs the dense DFT."""
     from repro.core.client import Context
     from repro.core.clients.dist_fft import DistFFTNDClient
-    from repro.core.plan import dist_local_lengths
+    from repro.core.candidates import dist_local_lengths
 
     problem = Problem((512, 512, 512), "Outplace_Complex", "float", batch=1)
     client = DistFFTNDClient(problem, Context())

@@ -13,7 +13,8 @@ import numpy as np
 import pytest
 
 from repro.core.client import Problem
-from repro.core.plan import Candidate, Plan, PlanCache, PlanRigor
+from repro.core.candidates import Candidate
+from repro.core.plan import Plan, PlanCache, PlanRigor
 from repro.core.results import (aggregate_rows, percentile,
                                 percentile_summary, Row)
 from repro.core.wisdom import Wisdom

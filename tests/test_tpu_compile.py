@@ -27,14 +27,18 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
 import repro.core.device as device
-from repro.core.candidates import TPU_WITHDRAWN
+from repro.core.backends import TABLE
+from repro.core.candidates import Candidate, candidates
 from repro.core.client import Problem
 from repro.core.clients.dist_fft import dist_engines
 from repro.core.clients.jax_fft import forward_fn
-from repro.core.plan import (Candidate, candidates, estimate_choice,
-                             fallback_chain)
+from repro.core.costmodel import estimate_choice
+from repro.core.plan import fallback_chain
 from repro.fft import distributed as dist
 from repro.roofline.hlo_parse import count_source_collectives
+
+
+TPU_WITHDRAWN = frozenset(b for b, rec in TABLE.items() if rec.tpu_withdrawn)
 
 
 @pytest.fixture(scope="module")

@@ -7,7 +7,8 @@ import json
 import pytest
 
 from repro.core.client import Problem
-from repro.core.plan import Candidate, PlanRigor, make_plan
+from repro.core.candidates import Candidate
+from repro.core.plan import PlanRigor, make_plan
 from repro.core.wisdom import (WISDOM_SCHEMA_VERSION, Wisdom,
                                _feasibility_class, _strip_shape_knobs)
 

@@ -112,7 +112,7 @@ def _annotate_roofline(rec: dict, problem, cand, best_s: float) -> None:
     modeled HBM bytes from the *active* cost model (so a fitted per-device
     table flows into roofline_frac too), and the achieved fraction of
     whichever wall binds (always finite for an ok row — an
-    :class:`~repro.core.costmodel.Infeasible` verdict degrades to the
+    :class:`~repro.core.backends.Infeasible` verdict degrades to the
     one-read+one-write algorithmic minimum, and the row is tagged and
     logged: a row that actually ran but models as infeasible means the
     model's feasibility rules have drifted from the kernels')."""
@@ -140,7 +140,7 @@ def bench_backend(backend: str, extents: tuple[int, ...], batch: int,
     import jax
     from repro.core.client import Problem
     from repro.core.extents import classify
-    from repro.core.plan import Candidate, backend_supports
+    from repro.core.candidates import Candidate, backend_supports
     from repro.core.clients.jax_fft import build_forward
 
     problem = Problem(extents, "Outplace_Complex", "float", batch=batch)
@@ -188,7 +188,7 @@ def bench_dist_backend(backend: str, extents: tuple[int, ...], batch: int,
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.core.client import Problem
     from repro.core.extents import classify
-    from repro.core.plan import Candidate, _pencil_mesh_shapes
+    from repro.core.candidates import Candidate, _pencil_mesh_shapes
     from repro.core.clients.dist_fft import dist_engines
     from repro.fft import distributed as dist
     from repro.launch.mesh import flat_mesh, reshaped_mesh

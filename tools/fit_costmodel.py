@@ -46,11 +46,12 @@ from dataclasses import dataclass
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.core.backends import TABLE  # noqa: E402
 from repro.core.candidates import Candidate  # noqa: E402
 from repro.core.client import Problem  # noqa: E402
 from repro.core.compare import BenchFormatError, load_bench  # noqa: E402
-from repro.core.costmodel import (BACKEND_COEFFS, DEFAULT_MODEL,  # noqa: E402
-                                  CostModel, save_tables, spearman)
+from repro.core.costmodel import (DEFAULT_MODEL, CostModel,  # noqa: E402
+                                  save_tables, spearman)
 from repro.core.extents import classify, parse_extents  # noqa: E402
 from repro.core.wisdom import Wisdom  # noqa: E402
 
@@ -75,6 +76,11 @@ class Obs:
 # ---------------------------------------------------------------------------
 # observation collection
 # ---------------------------------------------------------------------------
+def _fittable(backend: str) -> bool:
+    """A backend whose record names cost coefficients a measurement scales."""
+    return backend in TABLE and bool(TABLE[backend].coeffs)
+
+
 def bench_observations(paths: list[str]) -> tuple[list[Obs], dict]:
     """Grid rows of BENCH documents as observations.
 
@@ -106,7 +112,7 @@ def bench_observations(paths: list[str]) -> tuple[list[Obs], dict]:
                 skipped["bad time_ms"] += 1
                 continue
             backend = str(row.get("backend", ""))
-            if backend not in BACKEND_COEFFS:
+            if not _fittable(backend):
                 skipped[f"backend without coefficients ({backend})"] += 1
                 continue
             try:
@@ -148,7 +154,7 @@ def wisdom_observations(paths: list[str]) -> tuple[list[Obs], dict]:
                 if not math.isfinite(ms) or ms <= 0:
                     skipped["bad measured_ms"] += 1
                     continue
-                if cand.backend not in BACKEND_COEFFS or cand.mesh:
+                if not _fittable(cand.backend) or cand.mesh:
                     skipped[f"unfittable candidate ({cand.backend})"] += 1
                     continue
                 obs.append(Obs(kind, classify(problem.extents),
