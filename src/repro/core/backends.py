@@ -7,7 +7,9 @@ engine, its PATIENT knobs, its family (the ``fft_<family>_`` of every
 executable name), whether one engine call covers every axis, and whether
 the TPU withdraws it.  The planner (:mod:`repro.core.candidates`,
 :mod:`repro.core.costmodel`) and the clients read this table; the length
-caps are defined and compared here only.
+caps are compared here only, and defined here but for the jnp four-step's
+factorisation, which :mod:`repro.core.extents` states for its engine and
+this table both.
 
 :data:`TABLE` is in enumeration order: ESTIMATE's ties and the conformance
 matrix follow it.
@@ -20,7 +22,8 @@ from dataclasses import dataclass
 from functools import partial
 from typing import TYPE_CHECKING, Any, Callable
 
-from .extents import _factors_only, next_pow2 as _next_pow2, next_smooth
+from .extents import (FOURSTEP_MAX_RADIX, _factors_only, fourstep_levels,
+                      next_pow2 as _next_pow2, next_smooth)
 
 if TYPE_CHECKING:
     from .client import Problem
@@ -71,10 +74,6 @@ class Infeasible:
 # ---------------------------------------------------------------------------
 def _pow2(n: int) -> bool:
     return n >= 1 and (n & (n - 1)) == 0
-
-
-def _smooth(n: int) -> bool:
-    return n >= 1 and _factors_only(n, (2, 3, 5, 7, 11, 13))
 
 
 def _smooth7(n: int) -> bool:
@@ -197,13 +196,14 @@ def _stockham_passes(c, n: int) -> float:
     return c.stockham_stage_passes * float(max(1, n.bit_length() - 1))
 
 
+def _fourstep_fits(n: int) -> bool:
+    # every prime factor <= the largest radix (composites never divide
+    # once their primes are out)
+    return n >= 1 and _factors_only(n, range(2, FOURSTEP_MAX_RADIX + 1))
+
+
 def _fourstep_passes(c, n: int) -> float:
-    levels = 1
-    m = n
-    while m > 128:
-        m = -(-m // 128)
-        levels += 1
-    return c.fourstep_level_passes * levels
+    return c.fourstep_level_passes * fourstep_levels(n)
 
 
 def _bluestein_passes(c, n: int) -> float:
@@ -312,7 +312,7 @@ TABLE: dict[str, Backend] = {b.name: b for b in (
             whole_passes=_xla_whole),
     Backend("stockham", "jnp", fits=_pow2, passes=_stockham_passes,
             coeffs=("stockham_stage_passes",), engine=_jnp_engine("stockham")),
-    Backend("fourstep", "jnp", fits=_smooth, passes=_fourstep_passes,
+    Backend("fourstep", "jnp", fits=_fourstep_fits, passes=_fourstep_passes,
             coeffs=("fourstep_level_passes",), engine=_jnp_engine("fourstep")),
     Backend("dft", "pallas", fits=lambda n: n <= DFT_MAX_N,
             passes=lambda c, n: c.dft_passes, coeffs=("dft_passes",),

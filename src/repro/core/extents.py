@@ -56,6 +56,35 @@ def next_smooth(v: int, primes: Sequence[int] = (2, 3, 5, 7)) -> int:
     return v
 
 
+#: The jnp four-step engine's factorisation (:mod:`repro.fft.fourstep`),
+#: here so that the planner reads it without importing JAX: the largest
+#: column radix (the MXU tile edge) and the longest row the engine runs as
+#: one dense DFT matmul.
+FOURSTEP_MAX_RADIX = 128
+FOURSTEP_DENSE_MAX_N = 512
+
+
+def fourstep_split(n: int) -> tuple[int, int]:
+    """n = n1 * n2 for one four-step level: n1 the largest power of two up
+    to FOURSTEP_MAX_RADIX that divides n, else n's smallest odd prime."""
+    for cand in (128, 64, 32, 16, 8, 4, 2):
+        if n % cand == 0:
+            return cand, n // cand
+    for cand in range(3, FOURSTEP_MAX_RADIX + 1, 2):
+        if n % cand == 0:
+            return cand, n // cand
+    raise ValueError(
+        f"fourstep cannot factor n={n} with radices <= {FOURSTEP_MAX_RADIX}; "
+        "use the bluestein backend for large-prime lengths")
+
+
+def fourstep_levels(n: int) -> int:
+    """The dense DFT matmul passes the four-step runs at length n."""
+    if n <= FOURSTEP_DENSE_MAX_N:
+        return 1
+    return 1 + fourstep_levels(fourstep_split(n)[1])
+
+
 def classify(ext: Sequence[int]) -> str:
     """Paper extent classes: powerof2 | radix357 | oddshape."""
     if all(v & (v - 1) == 0 for v in ext):

@@ -12,7 +12,10 @@ i.e.  D = (W_n1 @ A  *  T) @ W_n2,  out = transpose(D).flatten()
 where A = x.reshape(n1, n2), W_r is the dense r-point DFT matrix and
 T[k1, j2] = W_n^{k1 j2} the twiddle grid.  Every flop lands in a matmul, so on
 TPU the whole transform runs on the 128x128 systolic MXU at high arithmetic
-intensity; the length-n2 row transform recurses until n2 <= 128.
+intensity; the length-n2 row transform recurses until n2 <= 512, which one
+dense matmul takes in fewer passes than a further split (6859 runs as
+19 x 361, 2^15 as 128 x 256).  Any length whose prime factors are all
+<= 128 factors (:func:`repro.core.extents.fourstep_split`).
 
 The Pallas kernel in ``repro/kernels/fft4step`` implements the n <= 16384 case
 (two 128-wide matmuls + fused twiddle, all resident in VMEM); this module is
@@ -25,10 +28,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .reference import dft_matrix, twiddles
+from repro.core.extents import FOURSTEP_DENSE_MAX_N, fourstep_split
 
-# Largest radix handled by a single dense DFT matmul; 128 == MXU tile edge.
-MAX_RADIX = 128
+from .reference import dft_matrix, twiddles
 
 #: The TPU's default f32 matmul is one bf16 pass, far outside the c64 error
 #: bound; HIGHEST keeps the DFT matmuls at full f32.
@@ -36,31 +38,18 @@ _PRECISION = jax.lax.Precision.HIGHEST
 
 
 def _base_dft(x: jnp.ndarray, inverse: bool) -> jnp.ndarray:
-    """Direct DFT via one matmul; n <= MAX_RADIX. W is symmetric -> x @ W."""
+    """Direct DFT via one matmul; n <= FOURSTEP_DENSE_MAX_N. W is
+    symmetric -> x @ W."""
     n = x.shape[-1]
     w = dft_matrix(n, inverse=inverse, dtype=x.dtype)
     return jnp.matmul(x, w, precision=_PRECISION)
 
 
-def _split(n: int) -> tuple[int, int]:
-    """Factor n = n1 * n2 with n1 as large as possible but <= MAX_RADIX."""
-    for cand in (128, 64, 32, 16, 8, 4, 2):
-        if n % cand == 0:
-            return cand, n // cand
-    # odd composite: peel the smallest odd prime factor <= MAX_RADIX
-    for cand in range(3, MAX_RADIX + 1, 2):
-        if n % cand == 0:
-            return cand, n // cand
-    raise ValueError(
-        f"fourstep cannot factor n={n} with radices <= {MAX_RADIX}; "
-        "use the bluestein backend for large-prime lengths")
-
-
 def _fft_unnormalized(x: jnp.ndarray, inverse: bool) -> jnp.ndarray:
     n = x.shape[-1]
-    if n <= MAX_RADIX:
+    if n <= FOURSTEP_DENSE_MAX_N:
         return _base_dft(x, inverse)
-    n1, n2 = _split(n)
+    n1, n2 = fourstep_split(n)
     batch = x.shape[:-1]
     a = x.reshape(*batch, n1, n2)
     w1 = dft_matrix(n1, inverse=inverse, dtype=x.dtype)
@@ -74,8 +63,8 @@ def _fft_unnormalized(x: jnp.ndarray, inverse: bool) -> jnp.ndarray:
 
 
 def fft(x: jnp.ndarray, inverse: bool = False) -> jnp.ndarray:
-    """Four-step FFT along the last axis. Length must factor into {2..128}
-    radices (any power of two, and most smooth sizes).
+    """Four-step FFT along the last axis. Every prime factor of the length
+    must be <= 128 (any power of two, and every 128-smooth size).
 
     Forward unnormalized, inverse scaled by 1/n (numpy semantics).
     """

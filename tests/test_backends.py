@@ -36,10 +36,10 @@ PINNED = {
          14.0, INF, INF, 15.0, INF, 20.0, INF, 23.0, INF, 24.0, INF],
         "jnp", False, False),
     "fourstep": (
-        "111111.111.1.1.11.1.1.1.",
-        "111111.111.1.1.11.1.1.1.",
-        [2.0, 2.0, 2.0, 2.0, 2.0, 2.0, INF, 4.0, 4.0, 4.0, INF, 4.0, INF,
-         4.0, INF, 6.0, 6.0, INF, 6.0, INF, 8.0, INF, 8.0, INF],
+        "11111111111111111.1.1.1.",
+        "11111111111111111.1.1.1.",
+        [2.0] * 10 + [4.0, 4.0, 4.0, 4.0, 6.0, 4.0, 4.0, INF, 6.0, INF,
+                      6.0, INF, 8.0, INF],
         "jnp", False, False),
     "dft": (
         "1111111111..............",

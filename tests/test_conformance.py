@@ -118,11 +118,13 @@ def _class_cells() -> list[tuple[str, str, str]]:
 
 def test_class_cells_cover_the_fused_nonpow2_paths():
     """The new fast paths must claim (and therefore test) their classes:
-    the mixed-radix kernel on radix357, the fused chirp on both."""
+    the mixed-radix kernel on radix357, the fused chirp on both, and the
+    four-step on oddshape lengths whose prime factors are <= 128."""
     covered = {(b, c) for b, c, _ in _class_cells()}
     assert ("stockham_pallas", "radix357") in covered
     assert ("chirpz_pallas", "radix357") in covered
     assert ("chirpz_pallas", "oddshape") in covered
+    assert ("fourstep", "oddshape") in covered
     assert ("bluestein", "oddshape") in covered
     assert ("xla", "oddshape") in covered
 

@@ -148,16 +148,17 @@ def test_estimate_choice_uses_model():
 
 
 def test_estimate_pins_nonpow2_classes():
-    """Acceptance pins: radix357 and oddshape extents plan onto fused
-    Pallas paths, never the xla / jnp-bluestein fallbacks."""
+    """Acceptance pins: radix357 extents plan onto fused Pallas paths and
+    oddshape ones onto the four-step matmuls, never the xla /
+    jnp-bluestein fallbacks."""
     fused = ("stockham_pallas", "fourstep_pallas", "chirpz_pallas")
     for kind in ("Outplace_Complex", "Outplace_Real"):
         # radix357 (e.g. 3072 = 3*2^10): one-touch mixed-radix territory
         assert estimate_choice(Problem((3072,), kind)).backend in fused
         assert estimate_choice(Problem((18432,), kind)).backend in fused
-        # oddshape (e.g. 6859 = 19^3): the fused chirp-Z
+        # oddshape (e.g. 6859 = 19^3): the four-step, as 19 x 361
         assert estimate_choice(
-            Problem((6859,), kind)).backend == "chirpz_pallas"
+            Problem((6859,), kind)).backend == "fourstep"
     # past the fourstep kernel's 16384 cap only the mixed-radix kernel
     # offers a single touch, so the pick is specific
     assert estimate_choice(

@@ -44,10 +44,11 @@ def test_golden_candidates_rank1_smooth():
 
 
 def test_golden_candidates_rank1_prime():
-    # 97: prime; dft, the single-pass fft4step (97 x 1) and the chirp
-    # paths still apply
+    # 97: prime; the jnp four-step's one dense radix (97 <= 128), dft, the
+    # single-pass fft4step (97 x 1) and the chirp paths still apply
     assert homogeneous_backends(Problem((97,), "Outplace_Complex")) == [
-        "xla", "dft", "fourstep_pallas", "chirpz_pallas", "bluestein"]
+        "xla", "fourstep", "dft", "fourstep_pallas", "chirpz_pallas",
+        "bluestein"]
 
 
 def test_golden_candidates_rank2_pow2_offers_fft2():
@@ -218,8 +219,9 @@ def test_odd_length_real_kinds_route_to_full_complex_chirp():
     even = estimate_bytes_moved(Problem((6860,), "Outplace_Real"),
                                 Candidate("bluestein"))
     assert odd > even
-    # and the ESTIMATE pick lands on the fused chirp, not xla/jnp-bluestein
-    assert estimate_choice(p).backend == "chirpz_pallas"
+    # and the ESTIMATE pick lands on the four-step (19 x 361, prime factors
+    # <= 128), not xla/jnp-bluestein
+    assert estimate_choice(p).backend == "fourstep"
 
 
 # --------------------------------------------------------------------------
@@ -263,8 +265,8 @@ def test_backends_registry_is_complete():
 TRAFFIC = Path(__file__).resolve().parents[1] / "bench" / "traffic"
 
 #: (mix, extents, batch) -> ESTIMATE's key for (Outplace_Complex,
-#: Outplace_Real) on the TPU.  Only the length-361 problems run the dense
-#: DFT; every other pick is the one the planner made before DFT_MAX_N.
+#: Outplace_Real) on the TPU.  6859 = 19^3 runs the jnp four-step as
+#: 19 x 361, which costs fewer modeled passes than XLA's FFT there.
 TPU_PICKS = {
     ("pow2", "1048576", 64): ("xla", "xla"),
     ("pow2", "4096x4096", 4): ("xla", "xla"),
@@ -273,7 +275,7 @@ TPU_PICKS = {
     ("pow2", "256x256", 1024): ("xla", "xla"),
     ("pow2", "128", 262144): ("dft", "dft"),
     ("nonpow2", "18432", 4096): ("xla", "fourstep_pallas"),
-    ("nonpow2", "6859", 8192): ("xla", "xla"),
+    ("nonpow2", "6859", 8192): ("fourstep", "fourstep"),
     ("nonpow2", "361x361", 384): ("dft", "dft"),
     ("nonpow2", "361x361x361", 1): ("dft", "dft"),
 }

@@ -17,7 +17,9 @@ imports this file.
 
 from __future__ import annotations
 
+import math
 import os
+import re
 
 import numpy as np
 import pytest
@@ -35,6 +37,7 @@ from repro.core.clients.jax_fft import forward_fn
 from repro.core.costmodel import estimate_choice
 from repro.core.plan import fallback_chain
 from repro.fft import distributed as dist
+from repro.fft import fourstep
 from repro.roofline.hlo_parse import count_source_collectives
 
 
@@ -118,10 +121,10 @@ def test_pallas_kernel_compiles(one_chip, on_tpu, kind, extents, batch,
 
 
 #: chip_smoke.SUITE problems whose picks the xla tests above do not
-#: already cover (6859, an XLA chirp, takes ~40 s to compile and is left
-#: to the chip run).
+#: already cover; 6859 = 19^3 at the nonpow2 cell's batch, planned onto
+#: the jnp four-step as 19 x 361.
 PLANNED = [((4096,), 16384), ((256, 256), 1024), ((18432,), 4096),
-           ((128,), 262144)]
+           ((128,), 262144), ((6859,), 8192)]
 
 
 @pytest.mark.parametrize("extents,batch", PLANNED,
@@ -134,6 +137,33 @@ def test_planned_suite_problem_compiles(one_chip, on_tpu, extents, batch):
         cand = estimate_choice(problem)
         assert not _backends(cand) & TPU_WITHDRAWN, cand.key()
         _compile_forward(problem, cand, one_chip)
+
+
+def _physical_minor_dims(text: str, size: int) -> set[int]:
+    """The minor-most dimension, by layout, of every array of at least
+    ``size`` elements that the compiled module's entry computation holds."""
+    entry = text[text.index("\nENTRY"):]
+    minors = set()
+    for dims, layout in re.findall(r"\b[a-z]+\d+\[([\d,]+)\]\{([\d,]+)",
+                                   entry):
+        ext = [int(d) for d in dims.split(",")]
+        if math.prod(ext) >= size:
+            minors.add(ext[int(layout.split(",")[0])])
+    return minors
+
+
+@pytest.mark.parametrize("inverse", [False, True], ids=["fwd", "inv"])
+def test_fourstep_6859_keeps_a_lane_dense_minor_axis(one_chip, on_tpu,
+                                                     inverse):
+    """At the nonpow2 cell's shape the jnp four-step's 19 x 361 passes
+    write no signal-sized array whose minor axis, as the compiler lays it
+    out, is narrower than a 128-lane vreg row."""
+    spec = jax.ShapeDtypeStruct((8192, 6859), jnp.complex64,
+                                sharding=one_chip)
+    text = jax.jit(lambda x: fourstep.fft(x, inverse=inverse)) \
+        .lower(spec).compile().as_text()
+    minors = _physical_minor_dims(text, 8192 * 6859)
+    assert minors and min(minors) >= 128, sorted(minors)
 
 
 def test_withdrawn_backends_never_offered_on_tpu(monkeypatch):
